@@ -49,6 +49,9 @@ def main() -> None:
 
     import importlib
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name, mod_name in BENCHES:

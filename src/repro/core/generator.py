@@ -457,14 +457,19 @@ class CandidateGenerator:
 
         Returns None when the fused program doesn't apply (no jax, non-PRF
         sources, loop backend, non-uniform tree counts) so the staged numpy
-        path takes over. Pool mode "host" scores the generator's own pool
-        on device — selections are bit-identical to the numpy path; pool
-        mode "device" draws the pool on device from the engine's threaded
-        PRNG key (different draws than the host rng — SEED NOTE).
+        path takes over; each such decline counts ``propose/declined`` and
+        ``propose/declined/<reason>``. Pool mode "host" scores the
+        generator's own pool on device — selections are bit-identical to the
+        numpy path; pool mode "device" draws the pool on device from the
+        engine's threaded PRNG key (different draws than the host rng — SEED
+        NOTE).
         """
         eng = self.propose_engine
         models = [s.model for s in active]
-        if eng is None or not eng.fusable(models):
+        reason = "no_jax" if eng is None else eng.decline_reason(models)
+        if reason:
+            _obs.count("propose/declined")
+            _obs.count(f"propose/declined/{reason}")
             return None
         descent = "pallas" if get_acquisition_backend() == "pallas" else "auto"
         incs = [s.incumbent for s in active]

@@ -19,7 +19,12 @@ Two pool modes (see ``acquisition.set_acquisition_pool``):
 * ``host`` — the generator's numpy pool is uploaded and only scoring +
   selection run on device, so the chosen indices are bit-identical to the
   staged numpy path (this is what the MFTune trajectory-identity test
-  pins).
+  pins). The pool goes up as its binary64 bit patterns and the split
+  thresholds as monotone uint64 order keys (``rank.monotone_keys``); the
+  program keys the pool with integer ops, so leaf routing compares the
+  host's exact binary64 values on any device: XLA:TPU keeps float64 as a
+  pair of float32, which rounds away the last bits that tell a pool value
+  from a threshold one ulp away.
 """
 
 from __future__ import annotations
@@ -30,6 +35,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import obs
+from ..kernels.forest_eval.ops import depth_bucket
 from .surrogate import ForestPlane, ProbabilisticRandomForest
 
 __all__ = ["ProposeEngine"]
@@ -40,6 +46,14 @@ _CONST_SIG = (4, False, False, False, False, 1)  # dropped knob: unit default
 # (measured crossover on XLA:CPU — below it the per-feature table gathers
 # cost more than the pointer-chasing they replace), gather descent below
 QS_AUTO_MIN = 32768
+
+
+def _shapes(*trees) -> tuple:
+    """Array shapes of device inputs: with the static arguments, what the
+    jitted program is compiled for."""
+    import jax
+
+    return tuple(a.shape for a in jax.tree.leaves(trees))
 
 
 def _default_rank_impl() -> str:
@@ -73,55 +87,78 @@ class ProposeEngine:
             return False
 
     @staticmethod
-    def fusable(models: Sequence) -> bool:
-        """True when the fused program applies: fitted PRFs on a packed
-        backend with a uniform tree count (the per-source slice contract)."""
+    def decline_reason(models: Sequence) -> str:
+        """Why the fused program does not apply to ``models`` ("" when it
+        does): it needs fitted PRFs on a packed backend with a uniform tree
+        count (the per-source slice contract)."""
         if not models:
-            return False
-        if not all(
-            isinstance(m, ProbabilisticRandomForest) and m.trees and m.backend != "loop"
-            for m in models
-        ):
-            return False
-        return len({len(m.trees) for m in models}) == 1
+            return "no_models"
+        if not all(isinstance(m, ProbabilisticRandomForest) and m.trees
+                   for m in models):
+            return "not_fitted_prf"
+        if any(m.backend == "loop" for m in models):
+            return "loop_backend"
+        if len({len(m.trees) for m in models}) != 1:
+            return "mixed_tree_counts"
+        return ""
+
+    @staticmethod
+    def fusable(models: Sequence) -> bool:
+        """True when the fused program applies (see :meth:`decline_reason`)."""
+        return not ProposeEngine.decline_reason(models)
 
     # --------------------------------------------------------------- uploads
     def _x64(self):
         import jax
-        return jax.experimental.enable_x64(True)
+        return jax.enable_x64(True)
 
     def _plane(self, models: Sequence) -> ForestPlane:
         from .acquisition import _plane_for
         return _plane_for([m.pack() for m in models])
 
-    def _arena_for(self, plane: ForestPlane) -> Tuple[tuple, tuple, Optional[tuple], str]:
+    def _arena_for(self, plane: ForestPlane, keyed: bool = False
+                   ) -> Tuple[tuple, tuple, Optional[tuple], str]:
         """Device-resident (arena, ystats, qs_plan, qs_reason) for a fused
-        plane, LRU-cached by plane identity. Unlike ``ops._device_arena``
+        plane, LRU-cached by plane identity. With ``keyed`` the arena's and
+        the plan's thresholds are the host-pool order keys instead of
+        float64 (same device arrays otherwise). Unlike ``ops._device_arena``
         this keeps the exact tree set (no power-of-two root padding):
         padded trees would pollute the per-source combine and double the
-        descent work. ``qs_plan`` is the uploaded merged QuickScorer table
+        descent work (the node arrays do pad, with unreachable leaves).
+        ``qs_plan`` is the uploaded merged QuickScorer table
         set (None when a tree exceeds 128 leaves — gather descent then,
         with the decline cause in ``qs_reason``)."""
         key = id(plane)
         hit = self._arena_cache.get(key)
-        if hit is not None and hit[0] is plane:
-            self._arena_cache.move_to_end(key)
-            return hit[1], hit[2], hit[3], hit[4]
-        import jax.numpy as jnp
+        if hit is None or hit[0] is not plane:
+            import jax.numpy as jnp
 
-        from ..kernels.forest_eval.propose import build_qs_plan_ex
+            from ..kernels.forest_eval.propose import build_qs_plan_ex
 
-        # the upload dtype follows the ambient x64 flag; entering the scope
-        # here keeps a direct caller outside propose()/score_topk() from
-        # silently caching a float32 arena
-        with self._x64():
-            return self._arena_upload(plane, jnp, build_qs_plan_ex, key)
+            # the upload dtype follows the ambient x64 flag; entering the
+            # scope here keeps a direct caller outside propose()/score_topk()
+            # from silently caching a float32 arena
+            with self._x64():
+                hit = self._arena_upload(plane, jnp, build_qs_plan_ex, key)
+        self._arena_cache.move_to_end(key)
+        _, arena, ystats, qs, qs_reason, arena_k, qs_k = hit
+        if keyed:
+            return arena_k, ystats, qs_k, qs_reason
+        return arena, ystats, qs, qs_reason
 
     def _arena_upload(self, plane, jnp, build_qs_plan_ex, key):
-        arena = tuple(jnp.asarray(a) for a in (
-            plane.feat, plane.thr, plane.child, plane.mean, plane.var,
-            plane.roots,
-        ))
+        from ..kernels.forest_eval.ops import pad_nodes
+        from ..kernels.forest_eval.rank import monotone_keys
+
+        # nodes pad to a power-of-two bucket so that refits reuse the
+        # compiled program; int32 indices (64-bit ints are emulated on TPU)
+        feat, thr, child, mean, var = pad_nodes(
+            plane.feat, plane.thr, plane.child, plane.mean, plane.var)
+        arena = (
+            jnp.asarray(feat, dtype=jnp.int32), jnp.asarray(thr),
+            jnp.asarray(child, dtype=jnp.int32), jnp.asarray(mean),
+            jnp.asarray(var), jnp.asarray(plane.roots, dtype=jnp.int32),
+        )
         # y_std**2 on host with the same python-float pow PackedForest.combine
         # uses, so the device denorm replays it exactly
         ystats = (
@@ -133,36 +170,33 @@ class ProposeEngine:
             plane.feat, plane.thr, plane.child, plane.mean, plane.var,
             plane.roots, self.space.dim,
         )
-        qs = None
+        arena_k = arena[:1] + (jnp.asarray(monotone_keys(thr, descending=False)),) + arena[2:]
+        qs = qs_k = None
         if qs_host is not None:
-            thrs, tabs, lm, lv, offs = qs_host
-            qs = (
-                tuple(jnp.asarray(a) for a in thrs),
-                tuple(jnp.asarray(a) for a in tabs),
-                jnp.asarray(lm), jnp.asarray(lv), jnp.asarray(offs),
-            )
-        self._arena_cache[key] = (plane, arena, ystats, qs, qs_reason)
+            qs = tuple(jnp.asarray(a) for a in qs_host)
+            qs_k = (jnp.asarray(monotone_keys(qs_host[0], descending=False)),) + qs[1:]
+        entry = (plane, arena, ystats, qs, qs_reason, arena_k, qs_k)
+        self._arena_cache[key] = entry
         while len(self._arena_cache) > self._arena_cache_max:
             self._arena_cache.popitem(last=False)
-        return arena, ystats, qs, qs_reason
+        return entry
 
-    def _tables_for(self, sample_space) -> Tuple[tuple, tuple]:
-        """Device transform tables for pool draws over ``sample_space``,
-        mapped onto the *full* space's column order (dropped knobs become
-        constant unit-default columns). Restrictions don't change a knob's
-        lo/hi/log, so the sample space's unit transform is the full space's.
+    def _tables_for(self, sample_space) -> dict:
+        """Device transform tables for pool draws over ``sample_space``
+        (``pack_draw_tables``), mapped onto the *full* space's column order
+        (dropped knobs become constant unit-default columns). Restrictions
+        don't change a knob's lo/hi/log, so the sample space's unit
+        transform is the full space's.
         """
         key = id(sample_space)
         hit = self._tables_cache.get(key)
         if hit is not None and hit[0] is sample_space:
             self._tables_cache.move_to_end(key)
-            return hit[1], hit[2]
+            return hit[1]
         import jax.numpy as jnp
 
-        with self._x64():
-            return self._tables_upload(sample_space, jnp, key)
+        from ..kernels.forest_eval.propose import pack_draw_tables
 
-    def _tables_upload(self, sample_space, jnp, key):
         ss_plane = sample_space.plane()
         sig_ss, cols_ss = ss_plane.device_tables()
         pos = {name: i for i, name in enumerate(sample_space.names)}
@@ -176,15 +210,17 @@ class ProposeEngine:
             i = pos.get(name)
             if i is None:
                 sig.append(_CONST_SIG)
-                cols.append((jnp.asarray(np.array([unit_default[j]])),))
+                cols.append((np.array([unit_default[j]]),))
             else:
                 sig.append(sig_ss[i])
-                cols.append(tuple(jnp.asarray(a) for a in cols_ss[i]))
-        entry = (sample_space, tuple(sig), tuple(cols))
-        self._tables_cache[key] = entry
+                cols.append(cols_ss[i])
+        with self._x64():
+            tabs = {name: jnp.asarray(a)
+                    for name, a in pack_draw_tables(sig, cols).items()}
+        self._tables_cache[key] = (sample_space, tabs)
         while len(self._tables_cache) > self._arena_cache_max:
             self._tables_cache.popitem(last=False)
-        return entry[1], entry[2]
+        return tabs
 
     def _next_key(self):
         import jax
@@ -229,7 +265,7 @@ class ProposeEngine:
             if tps is None:
                 raise ValueError("propose requires a uniform tree count per source")
             arena, ystats, qs, qs_reason = self._arena_for(plane)
-            sig, cols = self._tables_for(sample_space or self.space)
+            tabs = self._tables_for(sample_space or self.space)
             import jax.numpy as jnp
 
             n_pool = P.pool_bucket(pool_size or self.pool_size)
@@ -243,8 +279,10 @@ class ProposeEngine:
             S = len(plane.forests)
             inc = jnp.asarray(np.asarray(incumbents, dtype=float))
             w = jnp.asarray(np.asarray(weights, dtype=float))
-            static = ("propose", n_pool, plane.depth, S, tps, k, sig,
-                      rank_impl, descent, steps)
+            qs = qs if descent == "qs" else None
+            depth = depth_bucket(plane.depth)
+            static = ("propose", n_pool, depth, S, tps, k,
+                      _shapes(arena, qs, tabs), rank_impl, descent, steps)
             first = static not in self.compiled
             self.compiled.add(static)
             obs.count(f"rank_kernel/{rank_impl}")
@@ -254,21 +292,20 @@ class ProposeEngine:
                 obs.observe("propose/pool_occupancy", 1.0)
                 if steps is None:
                     idx, Xu, agg = P.propose_step(
-                        self._next_key(), cols, arena, ystats, inc, w,
-                        self._zero(), n_pool=n_pool, depth=plane.depth,
-                        n_sources=S, tps=tps, k=k, sig=sig, descent=descent,
-                        rank_impl=rank_impl,
-                        qs=qs if descent == "qs" else None,
+                        self._next_key(), tabs, arena, ystats, inc, w,
+                        self._zero(), n_pool=n_pool, depth=depth,
+                        n_sources=S, tps=tps, k=k, descent=descent,
+                        rank_impl=rank_impl, qs=qs,
                     )
                 else:
                     if self._key is None:
                         import jax
                         self._key = jax.random.PRNGKey(self.seed)
                     self._key, (idx, Xu, agg) = P.propose_scan(
-                        self._key, cols, arena, ystats, inc, w, self._zero(),
-                        n_pool=n_pool, depth=plane.depth, n_sources=S, tps=tps,
-                        k=k, sig=sig, descent=descent, rank_impl=rank_impl,
-                        steps=steps, qs=qs if descent == "qs" else None,
+                        self._key, tabs, arena, ystats, inc, w, self._zero(),
+                        n_pool=n_pool, depth=depth, n_sources=S, tps=tps,
+                        k=k, descent=descent, rank_impl=rank_impl,
+                        steps=steps, qs=qs,
                     )
                 return np.asarray(idx), np.asarray(Xu), np.asarray(agg)
 
@@ -284,7 +321,9 @@ class ProposeEngine:
     ) -> np.ndarray:
         """Host-pool mode: score an uploaded unit pool and return the top-n
         candidate indices, bit-identical to the staged numpy path
-        (``score_sources`` → ``aggregate_ranks`` → stable argsort)."""
+        (``score_sources`` → ``aggregate_ranks`` → stable argsort) on
+        XLA:CPU. The pool travels as its bit patterns and is routed on order
+        keys, so the leaf routing is the host's on every device."""
         from ..kernels.forest_eval import propose as P
 
         X_unit = np.atleast_2d(np.asarray(X_unit, dtype=float))
@@ -293,7 +332,7 @@ class ProposeEngine:
             tps = plane.uniform_tree_count
             if tps is None:
                 raise ValueError("score_topk requires a uniform tree count per source")
-            arena, ystats, qs, qs_reason = self._arena_for(plane)
+            arena, ystats, qs, qs_reason = self._arena_for(plane, keyed=True)
             import jax.numpy as jnp
 
             N, D = X_unit.shape
@@ -310,8 +349,10 @@ class ProposeEngine:
             S = len(plane.forests)
             inc = jnp.asarray(np.asarray(incumbents, dtype=float))
             w = jnp.asarray(np.asarray(weights, dtype=float))
-            static = ("score", bucket, plane.depth, S, tps, k, rank_impl,
-                      descent)
+            qs = qs if descent == "qs" else None
+            depth = depth_bucket(plane.depth)
+            static = ("score", bucket, depth, S, tps, k, _shapes(arena, qs),
+                      rank_impl, descent)
             first = static not in self.compiled
             self.compiled.add(static)
             obs.count(f"rank_kernel/{rank_impl}")
@@ -321,9 +362,8 @@ class ProposeEngine:
                 obs.observe("propose/pool_occupancy", N / bucket)
                 idx, _, _ = P.propose_step(
                     None, None, arena, ystats, inc, w, self._zero(),
-                    n_pool=bucket, depth=plane.depth, n_sources=S, tps=tps,
-                    k=k, sig=(), descent=descent, rank_impl=rank_impl,
-                    X=jnp.asarray(Xp), n_valid=N,
-                    qs=qs if descent == "qs" else None,
+                    n_pool=bucket, depth=depth, n_sources=S, tps=tps,
+                    k=k, descent=descent, rank_impl=rank_impl,
+                    X=jnp.asarray(Xp.view(np.uint64)), n_valid=N, qs=qs,
                 )
                 return np.asarray(idx)[: min(n, N)]

@@ -588,15 +588,15 @@ class SpacePlane:
         """Static per-knob signature + arrays for the on-device sampler.
 
         Returns ``(sig, cols)``: ``sig`` is a hashable tuple of per-knob
-        ``(kind, is_log, transformed, degenerate, zero_span, size)`` tuples
-        (a jit static argument for the fused propose step), ``cols`` the
-        matching tuple of per-knob numpy array tuples — numeric knobs get
+        ``(kind, is_log, transformed, degenerate, zero_span, size)`` tuples,
+        ``cols`` the matching tuple of per-knob numpy array tuples — numeric
+        knobs get
         ``(ga, gb, cum, mid, scal)`` with ``scal = [t_lo, t_span, lo, hi]``
         (the restriction-CDF tables plus the log-affine unit transform),
         categorical/bool knobs ``(act,)`` with the choice count carried in
-        the signature. The fused propose step uploads these once and
-        replays ``_quantile_col`` + clipped ``_to_unit_col`` per column on
-        device.
+        the signature. The fused propose step packs them into padded
+        arrays (``propose.pack_draw_tables``), uploads them once and
+        replays ``_quantile_col`` + clipped ``_to_unit_col`` on device.
         """
         sig, cols = [], []
         for j in range(len(self.space.knobs)):

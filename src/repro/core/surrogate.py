@@ -638,22 +638,21 @@ class ForestPlane:
                 )
                 m_t, v_t = np.take(self.mean, nid), np.take(self.var, nid)
         else:
-            tree_counts = {f.n_trees for f in self.forests}
-            if backend in ("jax", "auto") and len(tree_counts) == 1:
-                # uniform tree counts: descent + combine fuse on device
-                from ..kernels.forest_eval.ops import forest_plane_eval
+            from ..kernels.forest_eval.ops import (
+                available_backends, forest_eval, forest_plane_eval,
+            )
 
-                try:
-                    out = forest_plane_eval(
-                        self.feat, self.thr, self.child, self.mean, self.var,
-                        self.roots, X, self.depth, self.y_means, self.y_stds,
-                        trees_per_source=next(iter(tree_counts)),
-                    )
-                    _obs.count("forest_plane/fused_device")
-                    return out
-                except RuntimeError:
-                    pass  # no jax: fall through to the numpy-combine path
-            from ..kernels.forest_eval.ops import forest_eval
+            tree_counts = {f.n_trees for f in self.forests}
+            if (backend in ("jax", "auto") and len(tree_counts) == 1
+                    and "jax" in available_backends()):
+                # uniform tree counts: descent + combine fuse on device
+                out = forest_plane_eval(
+                    self.feat, self.thr, self.child, self.mean, self.var,
+                    self.roots, X, self.depth, self.y_means, self.y_stds,
+                    trees_per_source=next(iter(tree_counts)),
+                )
+                _obs.count("forest_plane/fused_device")
+                return out
 
             _obs.count("forest_plane/host_combine")
             m_t, v_t = forest_eval(

@@ -8,9 +8,12 @@ candidate axis is the one that scales with pool size. The descent itself is
 ``depth`` rounds of four gathers (feature, x-value, threshold, child); leaf
 self-loops make the loop body branch-free.
 
-Gathers use dynamic advanced indexing, which Mosaic does not lower on all
-TPU generations — like the other kernels in this package the wrapper
-defaults to ``interpret=True`` and the jnp reference carries CPU execution.
+Both kernels run interpreted on the CPU backend and through Mosaic on any
+other (:func:`interpret_mode`); there is no silent interpreter fallback on
+a TPU. Mosaic refuses both on a TPU v5e today, so there they raise their
+compile error: ``forest_eval_pallas`` with "Only 2D gather is supported",
+``chain_ordinals_pallas`` because its ``(1, d)`` permutation block breaks
+the (8, 128) tiling rule.
 """
 
 from __future__ import annotations
@@ -21,7 +24,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["forest_eval_pallas", "chain_ordinals_pallas"]
+__all__ = ["interpret_mode", "forest_eval_pallas", "chain_ordinals_pallas"]
+
+
+def interpret_mode() -> bool:
+    """Pallas interpret mode on the CPU backend, compiled kernels elsewhere."""
+    return jax.default_backend() == "cpu"
 
 
 def _forest_kernel(feat_ref, thr_ref, child_ref, mean_ref, var_ref, roots_ref,
@@ -49,7 +57,7 @@ def _forest_kernel(feat_ref, thr_ref, child_ref, mean_ref, var_ref, roots_ref,
 
 
 def forest_eval_pallas(feat, thr, child, mean, var, roots, X, depth,
-                       block_n: int = 128, interpret: bool = True):
+                       block_n: int = 128):
     """Per-tree leaf stats via the Pallas descent: (mean, var), each (T, N)."""
     T = roots.shape[0]
     N, D = X.shape
@@ -77,7 +85,7 @@ def forest_eval_pallas(feat, thr, child, mean, var, roots, X, depth,
             jax.ShapeDtypeStruct((T, N), mean.dtype),
             jax.ShapeDtypeStruct((T, N), var.dtype),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(feat, thr, child, mean, var, roots, X)
 
 
@@ -114,7 +122,7 @@ def _chain_kernel(wx_ref, wb_ref, perm_ref, idx_ref, *, d, n_words):
             suf = suf & jnp.take(wb, perm[k - 1], axis=1)
 
 
-def chain_ordinals_pallas(word_x, word_b, perms, interpret: bool = True):
+def chain_ordinals_pallas(word_x, word_b, perms):
     """(C, d+1, nb, T) exit-leaf ordinals via the Pallas chain walk.
 
     Accepts the ``ChainPlan.row_words`` layouts — (n, d, T) one-word or
@@ -129,7 +137,7 @@ def chain_ordinals_pallas(word_x, word_b, perms, interpret: bool = True):
         word_b = word_b[..., None]
     C, d, T, W = word_x.shape
     nb = word_b.shape[0]
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         idx = pl.pallas_call(
             functools.partial(_chain_kernel, d=d, n_words=W),
             grid=(C,),
@@ -140,7 +148,7 @@ def chain_ordinals_pallas(word_x, word_b, perms, interpret: bool = True):
             ],
             out_specs=pl.BlockSpec((1, d + 1, nb, T), lambda c: (c, 0, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((C, d + 1, nb, T), jnp.int32),
-            interpret=interpret,
+            interpret=interpret_mode(),
         )(jnp.asarray(word_x), jnp.asarray(word_b),
           jnp.asarray(perms, dtype=jnp.int32))
         return np.asarray(idx).astype(np.intp)

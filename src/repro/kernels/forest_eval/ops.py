@@ -9,9 +9,12 @@ per-tree leaf stats, shape (n_trees, n_points) each. Backends:
   pallas  — candidate-blocked gather kernel (``kernel.forest_eval_pallas``)
   auto    — jax when importable, else numpy
 
-The jax/pallas paths run under a scoped ``enable_x64`` so threshold
-comparisons happen in float64 — leaf routing, and therefore (mean, var),
-is bit-identical to the numpy plane. Arena sizes change on every refit, so
+The jax/pallas paths run under a scoped ``enable_x64`` and compare
+candidates with thresholds as monotone uint64 order keys of their float64
+values (``rank.monotone_keys``), made on the host: leaf routing, and
+therefore (mean, var), is bit-identical to the numpy plane on any device,
+including XLA:TPU, whose float64 is a pair of float32 that cannot hold
+every binary64 value. Arena sizes change on every refit, so
 node/root arrays are padded to power-of-two buckets (padding nodes are
 self-loop leaves) and the descent depth to a multiple of 4, keeping the
 jit cache small across Hyperband rungs.
@@ -49,8 +52,9 @@ def _pad_pow2(n: int) -> int:
     return 1 << max(3, int(n - 1).bit_length())
 
 
-def _pad_arena(feat, thr, child, mean, var, roots, depth):
-    """Bucket the arena so recompiles only happen on size-class changes."""
+def pad_nodes(feat, thr, child, mean, var):
+    """Pad the node arrays to a power-of-two bucket with unreachable
+    self-loop leaves, so a refit recompiles only on a size-class change."""
     n = len(feat)
     n_pad = _pad_pow2(n)
     if n_pad != n:
@@ -61,12 +65,23 @@ def _pad_arena(feat, thr, child, mean, var, roots, depth):
         child = np.concatenate([child, np.stack([self_idx, self_idx], axis=1).reshape(-1)])
         mean = np.concatenate([mean, np.zeros(extra)])
         var = np.concatenate([var, np.zeros(extra)])
+    return feat, thr, child, mean, var
+
+
+def depth_bucket(depth: int) -> int:
+    """Descent depth rounded up to a multiple of 4 (leaves self-loop, so
+    extra rounds are no-ops)."""
+    return -(-max(depth, 1) // 4) * 4
+
+
+def _pad_arena(feat, thr, child, mean, var, roots, depth):
+    """Bucket the arena so recompiles only happen on size-class changes."""
+    feat, thr, child, mean, var = pad_nodes(feat, thr, child, mean, var)
     t = len(roots)
     t_pad = _pad_pow2(t)
     if t_pad != t:
         roots = np.concatenate([roots, np.full(t_pad - t, roots[0], roots.dtype)])
-    depth_pad = -(-max(depth, 1) // 4) * 4
-    return feat, thr, child, mean, var, roots, depth_pad
+    return feat, thr, child, mean, var, roots, depth_bucket(depth)
 
 
 def _pad_pool(X):
@@ -79,8 +94,15 @@ def _pad_pool(X):
     return X, n
 
 
+def _keys(a):
+    from .rank import monotone_keys
+
+    return monotone_keys(a, descending=False)
+
+
 def _device_arena(feat, thr, child, mean, var, roots, depth):
-    """Pad and upload an arena once; reuse device buffers across predicts."""
+    """Pad and upload an arena once (thresholds as order keys, see
+    :func:`_keys`); reuse device buffers across predicts."""
     import jax.numpy as jnp
 
     key = id(feat)
@@ -91,7 +113,7 @@ def _device_arena(feat, thr, child, mean, var, roots, depth):
     padded = _pad_arena(feat, thr, child, mean, var, roots, depth)
     dev = (
         jnp.asarray(padded[0], jnp.int64),
-        jnp.asarray(padded[1], jnp.float64),
+        jnp.asarray(_keys(padded[1])),
         jnp.asarray(padded[2], jnp.int64),
         jnp.asarray(padded[3], jnp.float64),
         jnp.asarray(padded[4], jnp.float64),
@@ -104,8 +126,7 @@ def _device_arena(feat, thr, child, mean, var, roots, depth):
 
 
 def forest_eval(feat, thr, child, mean, var, roots, X, depth,
-                backend: str = "auto", interpret: bool = True,
-                block_n: int = 128,
+                backend: str = "auto", block_n: int = 128,
                 chunk_n: int = None) -> Tuple[np.ndarray, np.ndarray]:
     """Per-tree (mean, var) over the packed arena, each (n_trees, n_points).
 
@@ -122,7 +143,7 @@ def forest_eval(feat, thr, child, mean, var, roots, X, depth,
     if chunk_n is not None and X.shape[0] > chunk_n:
         parts = [
             forest_eval(feat, thr, child, mean, var, roots, X[a:a + chunk_n],
-                        depth, backend=backend, interpret=interpret, block_n=block_n)
+                        depth, backend=backend, block_n=block_n)
             for a in range(0, X.shape[0], chunk_n)
         ]
         return (np.concatenate([p[0] for p in parts], axis=1),
@@ -138,11 +159,11 @@ def forest_eval(feat, thr, child, mean, var, roots, X, depth,
         raise ValueError(f"unknown forest_eval backend {backend!r}")
     T = len(roots)
     X, n = _pad_pool(X)
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         import jax.numpy as jnp
 
         dev, depth = _device_arena(feat, thr, child, mean, var, roots, depth)
-        Xd = jnp.asarray(X, jnp.float64)
+        Xd = jnp.asarray(_keys(X))
         if backend == "jax":
             from .ref import forest_eval_ref
 
@@ -150,7 +171,7 @@ def forest_eval(feat, thr, child, mean, var, roots, X, depth,
         else:
             from .kernel import forest_eval_pallas
 
-            m_t, v_t = forest_eval_pallas(*dev, Xd, depth, block_n=block_n, interpret=interpret)
+            m_t, v_t = forest_eval_pallas(*dev, Xd, depth, block_n=block_n)
         return np.asarray(m_t)[:T, :n], np.asarray(v_t)[:T, :n]
 
 
@@ -161,14 +182,14 @@ def forest_plane_eval(feat, thr, child, mean, var, roots, X, depth,
     Descent *and* the per-source ensemble combine (law of total variance +
     denormalization) run on device; only (S, N) results are transferred.
     Requires a uniform tree count per source; raises RuntimeError without
-    jax so callers can fall back to the per-tree path.
+    jax (callers check :func:`available_backends` first).
     """
     if not _HAS_JAX:
         raise RuntimeError("forest_plane_eval requires jax; use the numpy plane")
     n_sources = len(roots) // trees_per_source
     X = np.atleast_2d(np.asarray(X, dtype=float))
     X, n = _pad_pool(X)
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         import jax.numpy as jnp
 
         from .ref import forest_plane_eval_ref
@@ -176,7 +197,7 @@ def forest_plane_eval(feat, thr, child, mean, var, roots, X, depth,
         dev, depth = _device_arena(feat, thr, child, mean, var, roots, depth)
         means, vars_ = forest_plane_eval_ref(
             *dev,
-            jnp.asarray(X, jnp.float64),
+            jnp.asarray(_keys(X)),
             jnp.asarray(y_means, jnp.float64),
             jnp.asarray(y_stds, jnp.float64),
             depth,

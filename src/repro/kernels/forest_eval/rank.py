@@ -23,16 +23,15 @@ remap* of the negated scores, in the package's usual triple pattern:
   ``"callback"`` hands the key halves to the numpy radix through a raw
   ``emit_python_callback`` primitive (on the CPU backend the "device"
   *is* the host, so the callback is a plain function call on the operand
-  buffers — the honest fast path inside the fused propose program);
-  ``"sort"`` keeps the monotone-key ``lax.sort`` as the portable
-  pure-XLA reference; ``"pallas"`` uses the histogram kernel below.
+  buffers — the honest fast path inside the fused propose program; it
+  exists only there); ``"sort"`` is the portable pure-XLA stable sort
+  (:func:`stable_argsort`, the only impl that compiles for a TPU);
+  ``"pallas"`` uses the histogram kernel below.
 * pallas (:func:`radix_rank_pallas`) — 8-bit histogram radix passes, one
   program per score row: digit histogram → exclusive prefix (digit base)
   → stable within-digit offsets from a blocked lower-triangular equality
-  count plus a running per-digit occupancy. Like the other kernels in
-  this package it defaults to ``interpret=True`` (dynamic scatters do not
-  lower on all TPU generations) and exists as the accelerator-shaped
-  formulation; the jnp/numpy paths carry CPU execution.
+  count plus a running per-digit occupancy. Interpreted on the CPU
+  backend; Mosaic refuses it on a TPU v5e.
 
 Scores must be NaN-free (numpy sorts any NaN last; the monotone remap
 would order -NaN first). EI scores — the only caller — are >= 0 or the
@@ -58,12 +57,15 @@ __all__ = [
     "RADIX_MIN_N",
     "RANK_IMPLS",
     "monotone_keys",
+    "keys_from_bits",
     "radix_argsort",
     "rank_rows_radix",
     "rank_rows_reference",
     "rank_rows",
     "default_rank_impl",
     "monotone_keys_traced",
+    "split_keys_argsort",
+    "stable_argsort",
     "rank_rows_traced",
     "radix_rank_pallas",
 ]
@@ -85,8 +87,9 @@ _MSB = np.uint64(1) << np.uint64(63)
 # ---------------------------------------------------------------------------
 
 
-def monotone_keys(scores: np.ndarray) -> np.ndarray:
-    """uint64 keys whose ascending order is the descending float order.
+def monotone_keys(scores: np.ndarray, descending: bool = True) -> np.ndarray:
+    """uint64 keys whose ascending order is the descending float order
+    (the ascending one with ``descending=False``).
 
     Everything happens in the integer domain: IEEE negation is a sign-bit
     XOR, ±0 detection is a bit-pattern test, and the classic monotone
@@ -101,7 +104,9 @@ def monotone_keys(scores: np.ndarray) -> np.ndarray:
     index order exactly like the stable numpy argsort.
     """
     x = np.ascontiguousarray(np.asarray(scores, dtype=np.float64))
-    bits = x.view(np.uint64) ^ _MSB  # negate: flip the sign bit
+    bits = x.view(np.uint64)
+    if descending:
+        bits = bits ^ _MSB  # negate: flip the sign bit
     bits = np.where((bits & ~_MSB) == 0, np.uint64(0), bits)  # ±0 -> +0
     sign = (bits >> np.uint64(63)).astype(bool)
     return np.where(sign, ~bits, bits | _MSB)
@@ -176,14 +181,88 @@ def default_rank_impl() -> str:
     return "callback" if jax.default_backend() == "cpu" else "sort"
 
 
-def monotone_keys_traced(scores):
+def monotone_keys_traced(scores, descending: bool = True):
     """Traced :func:`monotone_keys` — same all-integer remap, so the key
-    order survives XLA:CPU's FTZ/DAZ compute threads bit-exactly."""
+    order survives XLA:CPU's FTZ/DAZ compute threads bit-exactly.
+    ``descending=False`` skips the negation (ascending float order)."""
+    return keys_from_bits(lax.bitcast_convert_type(scores, jnp.uint64),
+                          descending)
+
+
+def keys_from_bits(bits, descending: bool = True):
+    """Traced :func:`monotone_keys` of float64 values given as their raw
+    uint64 bit patterns (``x.view(np.uint64)`` on the host). Integer ops
+    only, so it also runs on XLA:TPU, which refuses the f64 -> integer
+    bitcast and rounds uploaded float64 values to a float32 pair."""
     msb = jnp.uint64(1) << jnp.uint64(63)
-    bits = lax.bitcast_convert_type(scores, jnp.uint64) ^ msb
+    if descending:
+        bits = bits ^ msb
     bits = jnp.where((bits & ~msb) == 0, jnp.uint64(0), bits)
     sign = (bits >> jnp.uint64(63)).astype(bool)
     return jnp.where(sign, ~bits, bits | msb)
+
+
+def _argsort_keys(keys):
+    """int32 stable argsort along the last axis by a list of same-shape keys,
+    most significant first: LSD passes of a one-key stable sort that carries
+    the permutation as payload."""
+    perm = lax.broadcasted_iota(jnp.int32, keys[0].shape, keys[0].ndim - 1)
+    for i, key in enumerate(reversed(keys)):
+        if i:
+            key = jnp.take_along_axis(key, perm, axis=-1)
+        _, perm = lax.sort((key, perm), dimension=key.ndim - 1,
+                           is_stable=True, num_keys=1)
+    return perm
+
+
+def _monotone_u32(f):
+    """uint32 keys whose unsigned order is the float32 order of ``f``."""
+    bits = lax.bitcast_convert_type(f, jnp.uint32)
+    msb = jnp.uint32(1 << 31)
+    return jnp.where((bits & msb) != 0, ~bits, bits | msb)
+
+
+def split_keys_argsort(x, descending: bool = False):
+    """:func:`stable_argsort` without an f64 -> integer bitcast.
+
+    The float64 key splits into three float32 pieces, ``p0 = f32(x)``,
+    ``p1 = f32(x - p0)``, ``p2 = f32(x - p0 - p1)``: each subtraction is
+    exact and each rounding monotone, so the lexicographic order of the
+    pieces is the order of ``x`` for every finite value in float32's
+    normal range (±0 fold to one key first; values beyond it tie with
+    their float32 overflow). Each piece maps to a monotone uint32 key and
+    the sort runs as three stable uint32 passes: XLA:TPU compiles those in
+    seconds, where one f64-keyed sort of a 4096-wide row takes minutes.
+    """
+    k = -x if descending else x
+    r = jnp.where(k == 0, jnp.zeros_like(k), k)
+    keys = []
+    for _ in range(3):
+        p = r.astype(jnp.float32)
+        keys.append(_monotone_u32(p))
+        r = jnp.where(jnp.isfinite(p), r - p.astype(r.dtype), jnp.zeros_like(r))
+    return _argsort_keys(keys)
+
+
+def stable_argsort(x, descending: bool = False):
+    """int32 stable argsort of a float array along its last axis, ties
+    (±0 included) in index order — ``np.argsort(-x if descending else x,
+    kind="stable")``.
+
+    The key is chosen by the platform the program is lowered for. On
+    XLA:CPU it is the all-integer :func:`monotone_keys_traced` remap:
+    compute threads there run with FTZ/DAZ, and a float ``-x`` or
+    ``== 0.0`` would fold subnormal scores into the zero tie group. XLA:TPU
+    refuses f64 -> integer bitcasts, so every other platform sorts by
+    :func:`split_keys_argsort`.
+    """
+    def int_keys(v):
+        return _argsort_keys([monotone_keys_traced(v, descending)])
+
+    return lax.platform_dependent(
+        x, cpu=int_keys,
+        default=functools.partial(split_keys_argsort, descending=descending),
+    )
 
 
 def _rank_callback(lo_hi) -> np.ndarray:
@@ -218,12 +297,13 @@ def _rank_callback(lo_hi) -> np.ndarray:
 
 
 if jax is not None:
-    from jax._src import core as _jcore
-    from jax._src.interpreters import mlir as _jmlir
+    from jax.core import ShapedArray as _ShapedArray
+    from jax.extend.core import Primitive as _Primitive
+    from jax.interpreters import mlir as _jmlir
 
-    _rank_rows_p = _jcore.Primitive("repro_rank_rows")
+    _rank_rows_p = _Primitive("repro_rank_rows")
     _rank_rows_p.def_abstract_eval(
-        lambda aval: _jcore.ShapedArray(aval.shape[:-1], np.dtype(np.int32))
+        lambda aval: _ShapedArray(aval.shape[:-1], np.dtype(np.int32))
     )
     _rank_rows_p.def_impl(lambda lo_hi: _rank_callback(np.asarray(lo_hi)))
 
@@ -252,21 +332,18 @@ def rank_rows_traced(scores, impl: str):
     CPU). All three return the exact reference ranks.
     """
     if impl == "callback":
+        if jax.default_backend() != "cpu":
+            raise ValueError("rank impl 'callback' runs the host radix and "
+                             "exists only on the CPU backend")
         keys = monotone_keys_traced(scores)
         lo_hi = lax.bitcast_convert_type(keys, jnp.uint32)  # (..., 2) LE halves
         return _rank_rows_p.bind(lo_hi).astype(jnp.float64)
     if impl == "pallas":
         keys = monotone_keys_traced(scores)
-        return radix_rank_pallas(
-            keys, interpret=jax.default_backend() == "cpu"
-        )
+        return radix_rank_pallas(keys)
     if impl != "sort":
         raise ValueError(f"unknown rank impl {impl!r}; expected one of {RANK_IMPLS}")
-    keys = monotone_keys_traced(scores)
-    iota = jnp.broadcast_to(
-        jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :], scores.shape
-    )
-    _, perm = lax.sort((keys, iota), dimension=1, is_stable=True, num_keys=1)
+    perm = stable_argsort(scores, descending=True)
     iota_f = jnp.broadcast_to(
         jnp.arange(scores.shape[1], dtype=jnp.float64)[None, :], scores.shape
     )
@@ -321,11 +398,15 @@ def _radix_rank_kernel(keys_ref, rank_ref, *, n, occ_block):
     )
 
 
-def radix_rank_pallas(keys, interpret: bool = True):
+def radix_rank_pallas(keys):
     """Float rank matrix from (S, N) monotone u64 keys via the pallas
     histogram radix; N must be a multiple of the occupancy block (any
-    power-of-two pool bucket is)."""
+    power-of-two pool bucket is). Interpreted on the CPU backend only;
+    Mosaic refuses its ``(1, N)`` block on a TPU v5e (the (8, 128) tiling
+    rule), so there it raises its compile error."""
     from jax.experimental import pallas as pl
+
+    from .kernel import interpret_mode
 
     S, N = keys.shape
     occ_block = min(256, N)
@@ -337,5 +418,5 @@ def radix_rank_pallas(keys, interpret: bool = True):
         in_specs=[pl.BlockSpec((1, N), lambda s: (s, 0))],
         out_specs=pl.BlockSpec((1, N), lambda s: (s, 0)),
         out_shape=jax.ShapeDtypeStruct((S, N), jnp.float64),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(keys)

@@ -2,9 +2,11 @@
 
 Same node encoding as ``repro.core.surrogate.packed_descend``: leaves have
 ``thr = +inf`` and self-loop children, so the descent needs no active-lane
-masking — every lane converges to its leaf and then spins in place. Runs in
-whatever precision the inputs carry; the ops dispatcher feeds it float64
-(x64-scoped) so leaf routing is bit-identical to the numpy plane.
+masking — every lane converges to its leaf and then spins in place. It only
+compares candidates with thresholds, so it runs on whatever ordered dtype
+the inputs carry; the ops dispatcher feeds it uint64 order keys of the
+float64 values (x64-scoped) so leaf routing is bit-identical to the numpy
+plane on every device.
 """
 
 from __future__ import annotations

@@ -98,7 +98,7 @@ def test_rank_rows_dispatch_crossover():
 def test_rank_rows_traced_identity(impl):
     scores = _special_rows(seed=4, n_rows=3, n=129)
     want = R.rank_rows_reference(scores)
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         got = np.asarray(R.rank_rows_traced(jax.numpy.asarray(scores), impl))
     np.testing.assert_array_equal(got, want)
 
@@ -113,7 +113,7 @@ def test_rank_rows_traced_random_property(impl):
         # force tie clusters
         scores[rng.random((s, n)) < 0.3] = 0.25
         want = R.rank_rows_reference(scores)
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             got = np.asarray(R.rank_rows_traced(jax.numpy.asarray(scores), impl))
         np.testing.assert_array_equal(got, want)
 
