@@ -1,0 +1,12 @@
+"""Milliseconds per tuner iteration spent in the program's ``surrogate_fit``
+spans (``repro.obs``), over the traced window: their summed durations over
+the iterations completed. Spans of one name do not nest in one another."""
+
+NAME = "surrogate_fit"
+
+
+def read(ctx):
+    spans, n = ctx.get("spans"), ctx.get("steps")
+    if spans is None or not n:
+        return None
+    return 1000.0 * sum(d for name, d in spans if name == NAME) / n
