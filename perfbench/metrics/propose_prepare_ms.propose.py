@@ -1,0 +1,16 @@
+"""Milliseconds per propose call spent in the program's ``propose_prepare``
+spans (``repro.obs``), over the traced window: their summed durations over
+the calls completed. Spans of one name do not nest in one another. None
+where the program emits no such span, as before it had one."""
+
+NAME = "propose_prepare"
+
+
+def read(ctx):
+    spans, n = ctx.get("spans"), ctx.get("steps")
+    if spans is None or not n:
+        return None
+    durs = [d for name, d in spans if name == NAME]
+    if not durs:
+        return None
+    return 1000.0 * sum(durs) / n

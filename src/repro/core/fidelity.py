@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from .knowledge import TaskRecord
 from .similarity import kendall_tau
 
@@ -55,21 +56,24 @@ def collect_query_stats(
     tasks: Sequence[TaskRecord], weights: Dict[str, float], min_configs: int = 3
 ) -> List[QueryStats]:
     out: List[QueryStats] = []
-    for t in tasks:
-        obs = t.with_query_vectors()
-        if len(obs) < min_configs:
-            continue
-        w = weights.get(t.task_id, 0.0)
-        if t.task_id == "__target__":
-            w = weights.get("__target__", 0.0)
-        if w <= 0:
-            continue
-        perf = np.array([o.per_query_perf for o in obs], dtype=float)
-        cost = np.array(
-            [o.per_query_cost if o.per_query_cost is not None else o.per_query_perf for o in obs],
-            dtype=float,
-        )
-        out.append(QueryStats(task_id=t.task_id, perf=perf, cost=cost, weight=w))
+    with obs.span("fidelity_query_stats") as sp:
+        for t in tasks:
+            rows = t.with_query_vectors()
+            if len(rows) < min_configs:
+                continue
+            w = weights.get(t.task_id, 0.0)
+            if t.task_id == "__target__":
+                w = weights.get("__target__", 0.0)
+            if w <= 0:
+                continue
+            perf = np.array([o.per_query_perf for o in rows], dtype=float)
+            cost = np.array(
+                [o.per_query_cost if o.per_query_cost is not None else o.per_query_perf
+                 for o in rows],
+                dtype=float,
+            )
+            out.append(QueryStats(task_id=t.task_id, perf=perf, cost=cost, weight=w))
+        sp.set(sources=len(out), queries=out[0].perf.shape[1] if out else 0)
     return out
 
 
@@ -106,28 +110,32 @@ def greedy_query_subset(
     """Algorithm 2. Returns (subset indices, correlation score, cost ratio)."""
     if not stats:
         raise ValueError("no source stats for fidelity partitioning")
-    c = query_cost_ratios(stats)
-    m = len(c)
     subset: List[int] = []
     r = 0.0
     current_tau = 0.0
-    remaining = set(range(m))
-    while True:
-        best_q, best_tau = None, -np.inf
-        for q in sorted(remaining):
-            if r + c[q] > delta + 1e-12:
-                continue
-            tau = subset_correlation(stats, subset + [q])
-            if tau > best_tau:
-                best_q, best_tau = q, tau
-        if best_q is None:
-            break
-        subset.append(best_q)
-        remaining.discard(best_q)
-        r += c[best_q]
-        current_tau = best_tau
-        if current_tau >= 1.0 - 1e-12:
-            break
+    evals = 0
+    with obs.span("fidelity_greedy", delta=delta) as sp:
+        c = query_cost_ratios(stats)
+        m = len(c)
+        remaining = set(range(m))
+        while True:
+            best_q, best_tau = None, -np.inf
+            for q in sorted(remaining):
+                if r + c[q] > delta + 1e-12:
+                    continue
+                tau = subset_correlation(stats, subset + [q])
+                evals += 1
+                if tau > best_tau:
+                    best_q, best_tau = q, tau
+            if best_q is None:
+                break
+            subset.append(best_q)
+            remaining.discard(best_q)
+            r += c[best_q]
+            current_tau = best_tau
+            if current_tau >= 1.0 - 1e-12:
+                break
+        sp.set(queries=m, chosen=len(subset), evals=evals)
     return subset, current_tau, r
 
 

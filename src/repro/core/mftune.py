@@ -156,6 +156,7 @@ class MFTune:
             seed=self.opt.seed, backend=self.opt.hyperband_backend,
         )
         self.partition: Optional[FidelityPartition] = None
+        self._partition_attempts = 0
         self._mfo_activation_time: Optional[float] = None
         self._trajectory: List[TrajectoryPoint] = []
         self._n_eval = 0
@@ -322,7 +323,8 @@ class MFTune:
         if self.partition is not None or self.opt.fidelity_mode != "sql_selection":
             return
         t0 = _time.perf_counter()
-        with obs.span("fidelity_partition") as sp:
+        self._partition_attempts += 1
+        with obs.span("fidelity_partition", attempt=self._partition_attempts) as sp:
             sources = self.kb.same_query_sources(self.target) if self.opt.enable_transfer else []
             stats = collect_query_stats(sources, weights.weights)
             # degradation (§6.3): the current task becomes its own source once

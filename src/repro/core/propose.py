@@ -260,54 +260,60 @@ class ProposeEngine:
         from ..kernels.forest_eval import propose as P
 
         with self._x64():
-            plane = self._plane(models)
-            tps = plane.uniform_tree_count
-            if tps is None:
-                raise ValueError("propose requires a uniform tree count per source")
-            arena, ystats, qs, qs_reason = self._arena_for(plane)
-            tabs = self._tables_for(sample_space or self.space)
-            import jax.numpy as jnp
+            with obs.span("propose_prepare", mode="device_pool"):
+                plane = self._plane(models)
+                tps = plane.uniform_tree_count
+                if tps is None:
+                    raise ValueError("propose requires a uniform tree count per source")
+                arena, ystats, qs, qs_reason = self._arena_for(plane)
+                tabs = self._tables_for(sample_space or self.space)
+                import jax.numpy as jnp
 
-            n_pool = P.pool_bucket(pool_size or self.pool_size)
-            if descent == "auto":
-                descent = "qs" if qs is not None and n_pool >= QS_AUTO_MIN else "jax"
-            elif descent == "qs" and qs is None:
-                raise ValueError(f"no QuickScorer plan: {qs_reason}")
-            if rank_impl is None:
-                rank_impl = _default_rank_impl()
-            k = min(self._pow2(n + self.margin), n_pool)
-            S = len(plane.forests)
-            inc = jnp.asarray(np.asarray(incumbents, dtype=float))
-            w = jnp.asarray(np.asarray(weights, dtype=float))
-            qs = qs if descent == "qs" else None
-            depth = depth_bucket(plane.depth)
-            static = ("propose", n_pool, depth, S, tps, k,
-                      _shapes(arena, qs, tabs), rank_impl, descent, steps)
-            first = static not in self.compiled
-            self.compiled.add(static)
-            obs.count(f"rank_kernel/{rank_impl}")
+                n_pool = P.pool_bucket(pool_size or self.pool_size)
+                if descent == "auto":
+                    descent = "qs" if qs is not None and n_pool >= QS_AUTO_MIN else "jax"
+                elif descent == "qs" and qs is None:
+                    raise ValueError(f"no QuickScorer plan: {qs_reason}")
+                if rank_impl is None:
+                    rank_impl = _default_rank_impl()
+                k = min(self._pow2(n + self.margin), n_pool)
+                S = len(plane.forests)
+                inc = jnp.asarray(np.asarray(incumbents, dtype=float))
+                w = jnp.asarray(np.asarray(weights, dtype=float))
+                qs = qs if descent == "qs" else None
+                depth = depth_bucket(plane.depth)
+                self.compiled.add(("propose", n_pool, depth, S, tps, k,
+                                   _shapes(arena, qs, tabs), rank_impl,
+                                   descent, steps))
+            jitted = P._propose_jit if steps is None else P._propose_scan_jit
+            cached = jitted._cache_size()
             with obs.span("propose_step", mode="device_pool", bucket=n_pool,
-                          descent=descent, rank=rank_impl, sources=S, k=k,
-                          compile=first):
-                obs.observe("propose/pool_occupancy", 1.0)
-                if steps is None:
-                    idx, Xu, agg = P.propose_step(
-                        self._next_key(), tabs, arena, ystats, inc, w,
-                        self._zero(), n_pool=n_pool, depth=depth,
-                        n_sources=S, tps=tps, k=k, descent=descent,
-                        rank_impl=rank_impl, qs=qs,
-                    )
-                else:
-                    if self._key is None:
-                        import jax
-                        self._key = jax.random.PRNGKey(self.seed)
-                    self._key, (idx, Xu, agg) = P.propose_scan(
-                        self._key, tabs, arena, ystats, inc, w, self._zero(),
-                        n_pool=n_pool, depth=depth, n_sources=S, tps=tps,
-                        k=k, descent=descent, rank_impl=rank_impl,
-                        steps=steps, qs=qs,
-                    )
-                return np.asarray(idx), np.asarray(Xu), np.asarray(agg)
+                          descent=descent, rank=rank_impl, sources=S,
+                          k=k) as sp:
+                with obs.span("propose_dispatch"):
+                    if steps is None:
+                        idx, Xu, agg = P.propose_step(
+                            self._next_key(), tabs, arena, ystats, inc, w,
+                            self._zero(), n_pool=n_pool, depth=depth,
+                            n_sources=S, tps=tps, k=k, descent=descent,
+                            rank_impl=rank_impl, qs=qs,
+                        )
+                    else:
+                        if self._key is None:
+                            import jax
+                            self._key = jax.random.PRNGKey(self.seed)
+                        self._key, (idx, Xu, agg) = P.propose_scan(
+                            self._key, tabs, arena, ystats, inc, w,
+                            self._zero(), n_pool=n_pool, depth=depth,
+                            n_sources=S, tps=tps, k=k, descent=descent,
+                            rank_impl=rank_impl, steps=steps, qs=qs,
+                        )
+                with obs.span("propose_fetch"):
+                    out = np.asarray(idx), np.asarray(Xu), np.asarray(agg)
+                # the call compiled, or loaded from the persistent cache, a
+                # program this process had not run yet
+                sp.set(compile=jitted._cache_size() > cached)
+            return out
 
     def score_topk(
         self,
@@ -326,44 +332,49 @@ class ProposeEngine:
         keys, so the leaf routing is the host's on every device."""
         from ..kernels.forest_eval import propose as P
 
-        X_unit = np.atleast_2d(np.asarray(X_unit, dtype=float))
         with self._x64():
-            plane = self._plane(models)
-            tps = plane.uniform_tree_count
-            if tps is None:
-                raise ValueError("score_topk requires a uniform tree count per source")
-            arena, ystats, qs, qs_reason = self._arena_for(plane, keyed=True)
-            import jax.numpy as jnp
+            with obs.span("propose_prepare", mode="host_pool"):
+                X_unit = np.atleast_2d(np.asarray(X_unit, dtype=float))
+                plane = self._plane(models)
+                tps = plane.uniform_tree_count
+                if tps is None:
+                    raise ValueError("score_topk requires a uniform tree count per source")
+                arena, ystats, qs, qs_reason = self._arena_for(plane, keyed=True)
+                import jax.numpy as jnp
 
-            N, D = X_unit.shape
-            bucket = P.pool_bucket(N)
-            if descent == "auto":
-                descent = "qs" if qs is not None and bucket >= QS_AUTO_MIN else "jax"
-            elif descent == "qs" and qs is None:
-                raise ValueError(f"no QuickScorer plan: {qs_reason}")
-            if rank_impl is None:
-                rank_impl = _default_rank_impl()
-            Xp = np.zeros((bucket, D))
-            Xp[:N] = X_unit
-            k = min(self._pow2(n), bucket)
-            S = len(plane.forests)
-            inc = jnp.asarray(np.asarray(incumbents, dtype=float))
-            w = jnp.asarray(np.asarray(weights, dtype=float))
-            qs = qs if descent == "qs" else None
-            depth = depth_bucket(plane.depth)
-            static = ("score", bucket, depth, S, tps, k, _shapes(arena, qs),
-                      rank_impl, descent)
-            first = static not in self.compiled
-            self.compiled.add(static)
-            obs.count(f"rank_kernel/{rank_impl}")
+                N, D = X_unit.shape
+                bucket = P.pool_bucket(N)
+                if descent == "auto":
+                    descent = "qs" if qs is not None and bucket >= QS_AUTO_MIN else "jax"
+                elif descent == "qs" and qs is None:
+                    raise ValueError(f"no QuickScorer plan: {qs_reason}")
+                if rank_impl is None:
+                    rank_impl = _default_rank_impl()
+                Xp = np.zeros((bucket, D))
+                Xp[:N] = X_unit
+                k = min(self._pow2(n), bucket)
+                S = len(plane.forests)
+                inc = jnp.asarray(np.asarray(incumbents, dtype=float))
+                w = jnp.asarray(np.asarray(weights, dtype=float))
+                qs = qs if descent == "qs" else None
+                depth = depth_bucket(plane.depth)
+                self.compiled.add(("score", bucket, depth, S, tps, k,
+                                   _shapes(arena, qs), rank_impl, descent))
+                zi = self._zero()
+            cached = P._propose_jit._cache_size()
             with obs.span("propose_step", mode="host_pool", bucket=bucket,
                           descent=descent, rank=rank_impl, sources=S, k=k,
-                          compile=first, occupancy=N / bucket):
-                obs.observe("propose/pool_occupancy", N / bucket)
-                idx, _, _ = P.propose_step(
-                    None, None, arena, ystats, inc, w, self._zero(),
-                    n_pool=bucket, depth=depth, n_sources=S, tps=tps,
-                    k=k, descent=descent, rank_impl=rank_impl,
-                    X=jnp.asarray(Xp.view(np.uint64)), n_valid=N, qs=qs,
-                )
-                return np.asarray(idx)[: min(n, N)]
+                          occupancy=N / bucket) as sp:
+                with obs.span("propose_upload"):
+                    X = jnp.asarray(Xp.view(np.uint64))
+                with obs.span("propose_dispatch"):
+                    idx, _, _ = P.propose_step(
+                        None, None, arena, ystats, inc, w, zi,
+                        n_pool=bucket, depth=depth, n_sources=S, tps=tps,
+                        k=k, descent=descent, rank_impl=rank_impl,
+                        X=X, n_valid=N, qs=qs,
+                    )
+                with obs.span("propose_fetch"):
+                    idx = np.asarray(idx)
+                sp.set(compile=P._propose_jit._cache_size() > cached)
+            return idx[: min(n, N)]

@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 from scipy import stats
 
+from .. import obs as _obs
 from .gbm import GradientBoostedTrees
 from .knowledge import KnowledgeBase, TaskRecord
 from .space import ConfigSpace
@@ -198,6 +199,11 @@ class SimilarityEngine:
     def target_self_weight(self, target: TaskRecord) -> float:
         """Out-of-sample Kendall tau of the target surrogate via k-fold CV."""
         obs = target.full_fidelity()
+        with _obs.span("similarity_self_weight", n_obs=len(obs),
+                       folds=self.cv_folds):
+            return self._cv_tau(obs)
+
+    def _cv_tau(self, obs) -> float:
         if len(obs) < self.cv_folds + 1:
             return 0.0
         X = self.space.encode_many([o.config for o in obs])
@@ -227,13 +233,16 @@ class SimilarityEngine:
              np.array([o.performance for o in obs]))
             if len(obs) >= 3 else None
         )
-        for s in sources:
-            m = self.source_model(s.task_id)
-            if m is None:
-                continue
-            tau, p = eq2_similarity(self.space, m, target, target_Xy=target_Xy)
-            sims[s.task_id] = tau
-            pvals[s.task_id] = p
+        fitted = len(self._source_models)
+        with _obs.span("similarity_eq2", sources=len(sources)) as sp:
+            for s in sources:
+                m = self.source_model(s.task_id)
+                if m is None:
+                    continue
+                tau, p = eq2_similarity(self.space, m, target, target_Xy=target_Xy)
+                sims[s.task_id] = tau
+                pvals[s.task_id] = p
+            sp.set(fits=len(self._source_models) - fitted)
 
         # transition mechanism: majority of sources significant -> trust Eq. 2
         n_sig = sum(1 for p in pvals.values() if p < self.p_threshold)
@@ -242,10 +251,11 @@ class SimilarityEngine:
         if not use_eq2:
             # warm-start phase: predict similarity from meta-features
             if target.meta_features is not None:
-                self._ensure_meta_model(target)
-                for s in sources:
-                    if s.task_id in sims or True:  # overwrite with predictions
+                with _obs.span("similarity_meta"):
+                    self._ensure_meta_model(target)
+                    for s in sources:
                         if s.meta_features is not None and self.meta_model is not None:
+                            # overwrite Eq. 2 with predictions
                             sims[s.task_id] = self.meta_model.predict(
                                 target.meta_features, s.meta_features
                             )

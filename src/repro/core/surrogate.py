@@ -696,8 +696,12 @@ class ProbabilisticRandomForest(Surrogate):
     def fit(self, X: np.ndarray, y: np.ndarray) -> "ProbabilisticRandomForest":
         X = np.atleast_2d(np.asarray(X, dtype=float))
         y = np.asarray(y, dtype=float)
-        _obs.count("surrogate/fits")
-        _obs.observe("surrogate/fit_n_obs", float(len(y)))
+        with _obs.span("forest_fit", n_obs=len(y), dim=X.shape[1],
+                       trees=self.n_trees):
+            self._fit_trees(X, y)
+        return self
+
+    def _fit_trees(self, X: np.ndarray, y: np.ndarray) -> None:
         self.X_, self.y_ = X, y
         self._y_mean = float(y.mean()) if len(y) else 0.0
         self._y_std = float(y.std()) or 1.0
@@ -735,7 +739,6 @@ class ProbabilisticRandomForest(Surrogate):
             )
             tree.fit(X[boot[t]], yn[boot[t]])
             self.trees.append(tree)
-        return self
 
     def pack(self) -> PackedForest:
         """Stack all trees into one struct-of-arrays arena (cached per fit)."""

@@ -500,28 +500,37 @@ def _leaf_stats(arena, X, depth, descent):
 def _step_body(key, tabs, X, arena, qs, ystats, incumbents, weights, n_valid,
                zi, *, n_pool, depth, n_sources, tps, k, descent,
                rank_impl="sort"):
-    if X is None:
-        X = Xr = _draw_unit_pool(key, tabs, n_pool)
-    else:
-        # an uploaded pool is routed on the order keys of its binary64 bits
-        Xr = _rank.keys_from_bits(X, descending=False)
+    # the stage scopes name the device operations in HLO metadata, so a
+    # profile groups them by stage whatever XLA names the fusions
+    scope = jax.named_scope
+    with scope("draw"):
+        if X is None:
+            X = Xr = _draw_unit_pool(key, tabs, n_pool)
+        else:
+            # an uploaded pool is routed on the order keys of its binary64 bits
+            Xr = _rank.keys_from_bits(X, descending=False)
     mul = _seal_mul(zi)
     div = _seal_div(zi)
     kern = _kernels(zi)
-    if descent == "qs":
-        m_leaf, v_leaf = _qs_leaf_stats(qs, Xr)
-    else:
-        m_leaf, v_leaf = _leaf_stats(arena, Xr, depth, descent)
-    means, vars_ = _combine(m_leaf, v_leaf, ystats, n_sources, tps, mul, div)
-    scores = kern["ei"](means, vars_, incumbents[:, None])
-    valid = jnp.arange(X.shape[0]) < n_valid
-    # padding: EI = -1 < 0 <= any real EI, appended after real rows =>
-    # real rows keep their exact unpadded ranks under the stable sort
-    scores = jnp.where(valid[None, :], scores, -1.0)
-    agg = _aggregate_ranks_traced(scores, weights, n_sources, mul, rank_impl)
-    agg = jnp.where(valid, agg, jnp.inf)
-    idx = _sort_perm_asc1d(agg)[:k]
-    return idx, jnp.take(X, idx, axis=0), jnp.take(agg, idx)
+    with scope("descent"):
+        if descent == "qs":
+            m_leaf, v_leaf = _qs_leaf_stats(qs, Xr)
+        else:
+            m_leaf, v_leaf = _leaf_stats(arena, Xr, depth, descent)
+    with scope("combine"):
+        means, vars_ = _combine(m_leaf, v_leaf, ystats, n_sources, tps, mul, div)
+    with scope("ei"):
+        scores = kern["ei"](means, vars_, incumbents[:, None])
+        valid = jnp.arange(X.shape[0]) < n_valid
+        # padding: EI = -1 < 0 <= any real EI, appended after real rows =>
+        # real rows keep their exact unpadded ranks under the stable sort
+        scores = jnp.where(valid[None, :], scores, -1.0)
+    with scope("rank"):
+        agg = _aggregate_ranks_traced(scores, weights, n_sources, mul, rank_impl)
+        agg = jnp.where(valid, agg, jnp.inf)
+    with scope("topk"):
+        idx = _sort_perm_asc1d(agg)[:k]
+        return idx, jnp.take(X, idx, axis=0), jnp.take(agg, idx)
 
 
 @functools.partial(

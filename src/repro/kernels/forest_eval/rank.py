@@ -51,7 +51,6 @@ try:
 except ImportError:  # pragma: no cover - jax ships with the image
     jax = None
 
-from ... import obs as _obs
 
 __all__ = [
     "RADIX_MIN_N",
@@ -162,9 +161,7 @@ def rank_rows(scores: np.ndarray) -> np.ndarray:
     reference argsort below (both produce bit-identical ranks)."""
     scores = np.atleast_2d(np.asarray(scores, dtype=float))
     if scores.shape[1] >= RADIX_MIN_N:
-        _obs.count("rank_kernel/radix")
         return rank_rows_radix(scores)
-    _obs.count("rank_kernel/argsort")
     return rank_rows_reference(scores)
 
 

@@ -16,6 +16,11 @@ Design constraints (see docs/OBSERVABILITY.md):
 * **No RNG, no semantics.** Instrumentation never touches random state
   or alters control flow: trajectories are bit-identical tracer-on vs
   tracer-off at a fixed seed (pinned in ``tests/test_obs.py``).
+* **One clock with the device.** While a tracer is installed and JAX is
+  already imported, each span also enters a ``jax.profiler.TraceAnnotation``
+  of its name, so a ``jax.profiler`` trace shows the program's spans on the
+  profiler's own clock beside the device operations. This module never
+  imports JAX itself.
 
 Event vocabulary (validated against ``trace_schema.json``):
 
@@ -30,6 +35,7 @@ Event vocabulary (validated against ``trace_schema.json``):
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -43,11 +49,24 @@ __all__ = [
 ]
 
 
+# jax.profiler.TraceAnnotation once JAX has been imported by someone else
+_ANNOTATION = None
+
+
+def _annotation():
+    """The profiler's annotation class, or None while JAX is not loaded."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _ANNOTATION = getattr(profiler, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
 class Span:
     """A span in flight. Use as a context manager; ``set(**attrs)``
     attaches result attributes discovered mid-span (cost, cache hit...)."""
 
-    __slots__ = ("_tr", "name", "args", "id", "parent", "tid", "_t0")
+    __slots__ = ("_tr", "name", "args", "id", "parent", "tid", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tr = tracer
@@ -57,6 +76,7 @@ class Span:
         self.parent = -1
         self.tid = 0
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, **attrs: Any) -> "Span":
         self.args.update(attrs)
@@ -72,11 +92,17 @@ class Span:
         if stack:
             self.parent = stack[-1]
         stack.append(self.id)
+        ann = _annotation()
         self._t0 = time.perf_counter()
+        if ann is not None:
+            self._ann = ann(self.name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         tr = self._tr
         stack = tr._stacks.get(self.tid)
         if stack and stack[-1] == self.id:

@@ -341,3 +341,217 @@ def test_baselines_share_tracer_vocabulary():
         assert f"{cls.name}:tpch-100gb-A" in scopes
         for p in res.trajectory:
             assert p.wall_time > 1e9 and p.rung is None
+
+
+# ------------------------------------------------ spans inside the slow stages
+
+
+def _propose_setup(n_sources=3, n_trees=10, seed0=0):
+    from repro.core import (BoolKnob, CatKnob, ConfigSpace, FloatKnob, IntKnob,
+                            ProbabilisticRandomForest)
+
+    space = ConfigSpace([
+        FloatKnob("f1", 0.1, 10.0, log=True),
+        FloatKnob("f2", -5.0, 5.0),
+        IntKnob("i1", 1, 64, log=True),
+        CatKnob("c1", ["a", "b", "c"]),
+        BoolKnob("b1"),
+    ])
+    rng = np.random.default_rng(seed0)
+    models = [ProbabilisticRandomForest(n_trees=n_trees, seed=s).fit(
+                  rng.random((30, space.dim)), rng.random(30) * 10 + s)
+              for s in range(n_sources)]
+    incs = [5.0 + s for s in range(n_sources)]
+    ws = list(np.full(n_sources, 1.0 / n_sources))
+    return space, models, incs, ws
+
+
+PHASES = {"score_topk": ("propose_upload", "propose_dispatch", "propose_fetch"),
+          "propose": ("propose_dispatch", "propose_fetch")}
+
+
+@pytest.mark.parametrize("call", sorted(PHASES))
+def test_propose_phase_spans(call):
+    pytest.importorskip("jax")
+    from repro.core import ProposeEngine
+
+    space, models, incs, ws = _propose_setup()
+    eng = ProposeEngine(space, seed=0, pool_size=300)
+    pool = np.random.default_rng(1).random((300, space.dim))
+
+    def go():
+        if call == "score_topk":
+            eng.score_topk(models, pool, incs, ws, 4)
+        else:
+            eng.propose(models, incs, ws, 4)
+
+    go()  # compiles outside the trace
+    tr = obs.Tracer("phases")
+    with obs.tracing(tr):
+        go()
+    spans = _spans(tr)
+    by = {s["name"]: s for s in spans}
+    assert [s["name"] for s in spans] == ["propose_prepare", *PHASES[call], "propose_step"]
+    step, prep = by["propose_step"], by["propose_prepare"]
+    assert prep["parent"] == step["parent"] == -1
+    assert prep["ts"] + prep["dur"] <= step["ts"]
+    inner = [by[n] for n in PHASES[call]]
+    assert all(s["parent"] == step["id"] for s in inner)
+    assert sum(s["dur"] for s in inner) <= step["dur"]
+    assert step["args"]["compile"] is False
+
+
+def test_propose_step_compile_reads_the_jit_cache():
+    """A second engine calling a shape the process has run reads
+    ``compile=False``: the argument follows the jitted function's executable
+    cache, not the engine's own memory of its signatures."""
+    pytest.importorskip("jax")
+    from repro.core import ProposeEngine
+
+    # 2 sources of 7 trees: a signature no other test of this file compiles
+    space, models, incs, ws = _propose_setup(n_sources=2, n_trees=7, seed0=5)
+    pool = np.random.default_rng(2).random((260, space.dim))
+    tr = obs.Tracer("compile")
+    with obs.tracing(tr):
+        for seed in (0, 1):
+            ProposeEngine(space, seed=seed).score_topk(models, pool, incs, ws, 3)
+    flags = [s["args"]["compile"] for s in _spans(tr) if s["name"] == "propose_step"]
+    assert flags == [True, False]
+
+
+def test_forest_fit_spans_match_fits(monkeypatch):
+    from repro.core import ProbabilisticRandomForest
+
+    fits = []
+    orig = ProbabilisticRandomForest.fit
+
+    def counting_fit(self, X, y):
+        if obs.get_tracer() is not None:  # fits of the tuner's own run
+            fits.append(len(y))
+        return orig(self, X, y)
+
+    monkeypatch.setattr(ProbabilisticRandomForest, "fit", counting_fit)
+    _, _, _, tracer = _identity_run(traced=True)
+    spans = _spans(tracer)
+    fit_spans = [s for s in spans if s["name"] == "forest_fit"]
+    assert fits and len(fit_spans) == len(fits)
+    assert [s["args"]["n_obs"] for s in fit_spans] == fits
+    assert all(s["args"]["trees"] > 0 and s["args"]["dim"] > 0 for s in fit_spans)
+    # the partition is attempted until it forms; each try counts itself
+    attempts = [s["args"]["attempt"] for s in spans if s["name"] == "fidelity_partition"]
+    assert attempts == list(range(1, len(attempts) + 1))
+    names = {s["name"] for s in spans}
+    assert {"similarity_eq2", "similarity_self_weight"} <= names
+
+
+def test_fidelity_greedy_counts_correlation_evals(monkeypatch):
+    from repro.core import fidelity as F
+
+    rng = np.random.default_rng(0)
+    stats = [F.QueryStats(task_id=f"t{i}", perf=rng.random((12, 9)),
+                          cost=rng.random((12, 9)) + 0.1, weight=1.0 + i)
+             for i in range(3)]
+    calls = []
+    orig = F.subset_correlation
+
+    def counting(st, subset):
+        calls.append(tuple(subset))
+        return orig(st, subset)
+
+    monkeypatch.setattr(F, "subset_correlation", counting)
+    tr = obs.Tracer("greedy")
+    with obs.tracing(tr):
+        part = F.partition_fidelities(stats, [1 / 9, 1 / 3, 1.0])
+    greedy = [s for s in _spans(tr) if s["name"] == "fidelity_greedy"]
+    assert [s["args"]["delta"] for s in greedy] == [1 / 9, 1 / 3]
+    assert sum(s["args"]["evals"] for s in greedy) == len(calls) > 0
+    for s in greedy:
+        assert s["args"]["queries"] == 9
+        assert s["args"]["chosen"] == len(part.subsets[s["args"]["delta"]])
+
+
+def test_spans_mirror_on_the_profiler_clock(tmp_path):
+    """Each span's profiler annotation starts within 0.5 ms of the span put
+    on the profiler's clock by the benchmark's one-mark offset."""
+    import time
+
+    jax = pytest.importorskip("jax")
+    from perfbench.lib import trace_reduce as T
+    from repro.core import ProposeEngine
+
+    space, models, incs, ws = _propose_setup()
+    eng = ProposeEngine(space, seed=0)
+    pool = np.random.default_rng(3).random((300, space.dim))
+    eng.score_topk(models, pool, incs, ws, 4)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        tr = obs.Tracer("clock")
+        with obs.tracing(tr):
+            clock_pc = time.perf_counter()
+            with jax.profiler.TraceAnnotation(T.CLOCK_MARK):
+                pass
+            for _ in range(3):
+                with obs.span("outer"):
+                    eng.score_topk(models, pool, incs, ws, 4)
+                    time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    pd = T.load(T.xplane_file(str(tmp_path)))
+    shift = T.host_marks(pd, T.CLOCK_MARK)[0][0] - clock_pc
+    spans = _spans(tr)
+    assert len(spans) == 3 * 6
+    for name in {s["name"] for s in spans}:
+        moved = sorted(tr.epoch + s["ts"] + shift for s in spans if s["name"] == name)
+        marks = [a for a, _ in T.host_marks(pd, name)]
+        assert len(marks) == len(moved), name
+        assert max(abs(a - b) for a, b in zip(marks, moved)) <= 0.5e-3, name
+
+
+def test_no_annotation_without_tracer(monkeypatch):
+    from repro.obs import trace
+
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            entered.append("/" + self.name)
+
+    monkeypatch.setattr(trace, "_ANNOTATION", Annotation)
+    with obs.span("off"):
+        pass
+    assert entered == []
+    with obs.tracing(obs.Tracer("on")):
+        with obs.span("on"):
+            pass
+    assert entered == ["on", "/on"]
+
+
+def test_lowered_step_names_every_stage(monkeypatch):
+    jax = pytest.importorskip("jax")
+    from repro.core import ProposeEngine
+    from repro.kernels.forest_eval import propose as P
+
+    space, models, incs, ws = _propose_setup()
+    seen = []
+    orig = P._propose_jit
+
+    def capture(*a, **kw):
+        seen.append((a, kw))
+        return orig(*a, **kw)
+
+    capture._cache_size = orig._cache_size
+    monkeypatch.setattr(P, "_propose_jit", capture)
+    ProposeEngine(space, seed=0).propose(models, incs, ws, 4)
+    (a, kw), = seen
+    with jax.enable_x64(True):
+        text = orig.lower(*a, **kw).as_text(debug_info=True)
+    for stage in ("draw", "descent", "combine", "ei", "rank", "topk"):
+        assert f"/{stage}/" in text, stage
