@@ -444,30 +444,28 @@ def test_forest_fit_spans_match_fits(monkeypatch):
     assert {"similarity_eq2", "similarity_self_weight"} <= names
 
 
-def test_fidelity_greedy_counts_correlation_evals(monkeypatch):
+def test_fidelity_greedy_counts_correlation_evals():
     from repro.core import fidelity as F
+    from test_fidelity_batched import reference_greedy
 
     rng = np.random.default_rng(0)
     stats = [F.QueryStats(task_id=f"t{i}", perf=rng.random((12, 9)),
                           cost=rng.random((12, 9)) + 0.1, weight=1.0 + i)
              for i in range(3)]
-    calls = []
-    orig = F.subset_correlation
-
-    def counting(st, subset):
-        calls.append(tuple(subset))
-        return orig(st, subset)
-
-    monkeypatch.setattr(F, "subset_correlation", counting)
     tr = obs.Tracer("greedy")
     with obs.tracing(tr):
         part = F.partition_fidelities(stats, [1 / 9, 1 / 3, 1.0])
     greedy = [s for s in _spans(tr) if s["name"] == "fidelity_greedy"]
     assert [s["args"]["delta"] for s in greedy] == [1 / 9, 1 / 3]
-    assert sum(s["args"]["evals"] for s in greedy) == len(calls) > 0
+    # evals counts the candidate subsets scored, as the scalar search's
+    # subset_correlation calls did
+    ref_evals = [reference_greedy(stats, s["args"]["delta"])[3] for s in greedy]
+    assert [s["args"]["evals"] for s in greedy] == ref_evals
+    assert sum(ref_evals) > 0
     for s in greedy:
         assert s["args"]["queries"] == 9
         assert s["args"]["chosen"] == len(part.subsets[s["args"]["delta"]])
+        assert s["args"]["scalar_sources"] == 0
 
 
 def test_spans_mirror_on_the_profiler_clock(tmp_path):
