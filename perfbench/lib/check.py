@@ -108,3 +108,11 @@ def worst(numbers: Sequence[Dict[str, float]]) -> Dict[str, float]:
         for key, v in d.items():
             out[key] = max(out.get(key, 0.0), v)
     return out
+
+
+def verdict(readings: Sequence[Dict[str, float]], limits: Dict[str, float]) -> bool:
+    """``correct``: some call was checked, and each number's worst reading
+    is within its limit (a number that no call read fails)."""
+    numbers = worst(readings)
+    return bool(readings) and all(numbers.get(k, float("inf")) <= v
+                                  for k, v in limits.items())

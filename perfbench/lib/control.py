@@ -50,7 +50,7 @@ def readings(name: str, seed: int, rehearse: bool = False,
     out = []
     if kind == "score_topk":
         models, incs, ws = sd.fit_sources(cfg, seed)
-        X = sd.host_pools(1, traffic["pool"], seed)[0]
+        X = sd.host_pools(sd.space_of(cfg), 1, traffic["pool"], seed)[0]
         F = [sd.forest_data(m) for m in models]
         idx, agg = C.control_call(F, X, incs, ws, traffic["k"], dtype)
         out.append(C.check_call(F, X, incs, ws, idx, agg))

@@ -42,12 +42,6 @@ from . import check as C
 from . import setup_data as sd
 
 
-def _space():
-    from repro.sparksim import spark_space
-
-    return spark_space()
-
-
 def _shapes(models, N, D, depth, k) -> Dict:
     """The shapes ``counts.propose_counts`` takes, for one call."""
     return {"N": N, "D": D, "S": len(models), "T": models[0].n_trees,
@@ -100,11 +94,12 @@ class ScoreTopk:
     def __init__(self, cfg: Dict, traffic: Dict, seed: int):
         from repro.core import ProposeEngine
 
+        space = sd.space_of(cfg)
         self.models, self.incs, self.ws = sd.fit_sources(cfg, seed)
-        self.pools = sd.host_pools(traffic["pools"], traffic["pool"], seed)
+        self.pools = sd.host_pools(space, traffic["pools"], traffic["pool"], seed)
         self.k = traffic["k"]
         self.descent = traffic["descent"]
-        self.eng = ProposeEngine(_space(), seed=sd.spawn_seeds(seed, 1, sd.ENGINE)[0])
+        self.eng = ProposeEngine(space, seed=sd.spawn_seeds(seed, 1, sd.ENGINE)[0])
         self.probe = _ProposeProbe()
         self.calls: List = []
         self.n_pool = traffic["pool"]
@@ -187,9 +182,9 @@ class Tune:
         self.reference_result = None
 
     def session(self, records, tuner_seed: int):
-        """One ``MFTune.run`` session on a fresh tuner and knowledge base:
-        its (evaluations, best latency, iterations) and its recorded
-        ``score_topk`` calls."""
+        """One ``MFTune.run`` session on a fresh tuner and knowledge base, in
+        the configuration's knob space: its (evaluations, best latency,
+        iterations) and its recorded ``score_topk`` calls."""
         from repro import obs
         from repro.core import KnowledgeBase, MFTune, MFTuneOptions
         from repro.sparksim import SparkWorkload
@@ -199,7 +194,8 @@ class Tune:
         kb = KnowledgeBase()
         for r in records:
             kb.add_task(r, persist=False)
-        wl = SparkWorkload(t["benchmark"], t["scale_factor_gb"], t["cluster"])
+        wl = SparkWorkload(t["benchmark"], t["scale_factor_gb"], t["cluster"],
+                           space=sd.space_of(self.cfg))
         opts = MFTuneOptions(
             seed=tuner_seed,
             acquisition_backend=self.traffic["acquisition_backend"],

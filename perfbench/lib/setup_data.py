@@ -1,5 +1,9 @@
 """Inputs of a run, made from ``--seed`` during set-up.
 
+Every input is made in the knob space the configuration names
+(``space_of``): the histories' workloads, the sources' encodings and the
+pools.
+
 Histories are the paper's knowledge base made cheaply: each history task of
 the 32-task grid (other than the configuration's target) gets ``n_obs``
 Latin-hypercube configurations evaluated by sparksim, with their per-query
@@ -41,11 +45,64 @@ def history_specs(target: str, n_tasks: int):
     return [specs[(i * len(specs)) // n_tasks] for i in range(n_tasks)]
 
 
-def lhs_history(spec, n_obs: int, seed: int):
-    """A ``TaskRecord`` of ``n_obs`` LHS configurations run by sparksim."""
-    from repro.core.knowledge import Observation, TaskRecord
+def space_of(cfg: Dict):
+    """The knob space a configuration names: ``cfg["space"]["name"]`` is a
+    function exported by ``repro.sparksim``, declared to return a
+    ``ConfigSpace``, whose space has ``cfg["space"]["knobs"]`` knobs. No other
+    callable is called. The configuration's ``surrogate`` has to be the PRF
+    at its defaults (``_check_surrogate``)."""
+    import inspect
+    import typing
 
-    wl = spec.workload()
+    import repro.sparksim as sparksim
+    from repro.core.space import ConfigSpace
+
+    _check_surrogate(cfg)
+    name, knobs = cfg["space"]["name"], cfg["space"]["knobs"]
+    make = getattr(sparksim, name, None) if not name.startswith("_") else None
+    try:
+        returns = typing.get_type_hints(make).get("return") if inspect.isfunction(make) else None
+    except NameError:
+        returns = None
+    if returns is not ConfigSpace:
+        raise KeyError(f"repro.sparksim exports no knob space {name!r}")
+    space = make()
+    if space.dim != knobs:
+        raise ValueError(f"the configuration states {knobs} knobs for "
+                         f"{name!r}, which has {space.dim}")
+    return space
+
+
+# a configuration's ``surrogate`` keys, as the PRF's arguments
+PRF_ARGS = {"trees": "n_trees", "max_depth": "max_depth",
+            "min_samples_split": "min_samples_split", "bootstrap": "bootstrap"}
+
+
+def _check_surrogate(cfg: Dict) -> None:
+    """Every forest the program grows, the tuner's and the sources' alike, is
+    a PRF at its defaults: a configuration that states another surrogate
+    raises ``ValueError``."""
+    import inspect
+
+    from repro.core import ProbabilisticRandomForest
+
+    params = inspect.signature(ProbabilisticRandomForest).parameters
+    prf = {"kind": "probabilistic_random_forest",
+           **{k: params[a].default for k, a in PRF_ARGS.items()}}
+    if cfg["surrogate"] != prf:
+        raise ValueError(f"the configuration states the surrogate "
+                         f"{cfg['surrogate']}; the program grows only {prf}")
+
+
+def lhs_history(spec, space, n_obs: int, seed: int):
+    """A ``TaskRecord`` of ``n_obs`` LHS configurations of ``space`` run by
+    sparksim on the grid task ``spec``."""
+    from repro.core.knowledge import Observation, TaskRecord
+    from repro.sparksim import SparkWorkload
+
+    # the cost model's noise seed that ``TaskSpec.workload`` gives
+    wl = SparkWorkload(spec.benchmark, spec.data_gb, spec.hardware,
+                       seed=1234, space=space)
     rng = np.random.default_rng(seed)
     batch = wl.space.lhs_sample(rng, n_obs)
     cfgs = [dict(c) for c in batch]
@@ -74,7 +131,8 @@ def knowledge_base(cfg: Dict, seed: int, picks: Sequence[int] = None) -> List:
     specs = history_specs(cfg["target"]["task_id"], kb["history_tasks"])
     seeds = spawn_seeds(seed, len(specs), HISTORIES)
     idx = range(len(specs)) if picks is None else picks
-    return [lhs_history(specs[i], kb["observations_per_task"], seeds[i])
+    space = space_of(cfg)
+    return [lhs_history(specs[i], space, kb["observations_per_task"], seeds[i])
             for i in idx]
 
 
@@ -83,9 +141,8 @@ def fit_sources(cfg: Dict, seed: int):
     task drawn from the seed, with the incumbent (best latency) of each and
     Dirichlet weights."""
     from repro.core import make_forest
-    from repro.sparksim import spark_space
 
-    space = spark_space()
+    space = space_of(cfg)
     n_sources = cfg["knowledge_base"]["sources"]
     rng = np.random.default_rng(spawn_seeds(seed, 1, SOURCES)[0])
     picks = rng.choice(cfg["knowledge_base"]["history_tasks"],
@@ -101,12 +158,9 @@ def fit_sources(cfg: Dict, seed: int):
     return models, incs, [float(w) for w in weights]
 
 
-def host_pools(n_pools: int, n: int, seed: int) -> List[np.ndarray]:
-    """``n_pools`` unit-space pools of ``n`` uniform configurations of the
-    60-knob space, as the generator draws its host pools."""
-    from repro.sparksim import spark_space
-
-    space = spark_space()
+def host_pools(space, n_pools: int, n: int, seed: int) -> List[np.ndarray]:
+    """``n_pools`` unit-space pools of ``n`` uniform configurations of
+    ``space``, as the generator draws its host pools."""
     out = []
     for s in spawn_seeds(seed, n_pools, POOLS):
         out.append(space.sample(np.random.default_rng(s), n).unit())

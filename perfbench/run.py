@@ -43,6 +43,7 @@ import shutil  # noqa: E402
 import statistics  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
+import tempfile  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for _p in (os.path.join(ROOT, "src"), ROOT):
@@ -51,7 +52,6 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
 
 from perfbench.lib import spec  # noqa: E402
 
-TRACE_DIR = os.path.join(ROOT, ".cache", "perfbench", "trace")
 WARMED_DIR = os.path.join(ROOT, ".cache", "perfbench", "warmed")
 CHECK_STREAM = 7
 NO_CHIP_RC = 3
@@ -187,11 +187,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     if trace:
         from repro import obs
 
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        # a directory of this run's own, so runs that trace at once in one
+        # checkout keep their traces apart
+        trace_dir = tempfile.mkdtemp(prefix="perfbench-trace-")
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.enable_hlo_proto = False
-        jax.profiler.start_trace(TRACE_DIR, profiler_options=opts)
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
         tracer = obs.Tracer("perfbench")
         obs.set_tracer(tracer)
         annotate = jax.profiler.TraceAnnotation
@@ -225,8 +227,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     ref_s = time.perf_counter() - t_ref
     numbers = C.worst(readings)
     failed = sum(any(r.get(k, 0.0) > v for k, v in limits.items()) for r in readings)
-    correct = bool(readings) and all(numbers.get(k, float("inf")) <= v
-                                     for k, v in limits.items())
+    correct = C.verdict(readings, limits)
     _log(f"check: {len(readings)} calls of units {picks} against the float64 "
          f"reference in {ref_s!r} s; compile {log.of('check')}")
 
@@ -251,7 +252,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             if peak is not None:
                 ctx["least"] = counts.least_seconds(ctx["counts"], peak)
             _log(f"least time per call: {ctx['counts']} -> {ctx.get('least')}")
-        ctx["trace"] = _reduce_trace(host_spans, clock_pc, rehearse)
+        try:
+            ctx["trace"] = _reduce_trace(trace_dir, host_spans, clock_pc, rehearse)
+        finally:
+            shutil.rmtree(trace_dir, ignore_errors=True)
         for m in spec.metrics_of(bench, name, "per_layer"):
             v = spec.reader(m["name"])(ctx)
             if v is not None:
@@ -269,12 +273,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     return result
 
 
-def _reduce_trace(host_spans, clock_pc: float, rehearse: bool) -> dict:
-    """Reduce the window's profiler trace; the program's spans go onto the
-    trace's clock through the clock-sync annotation."""
+def _reduce_trace(trace_dir: str, host_spans, clock_pc: float,
+                  rehearse: bool) -> dict:
+    """Reduce the window's profiler trace in ``trace_dir``; the program's
+    spans go onto the trace's clock through the clock-sync annotation."""
     from perfbench.lib import trace_reduce as T
 
-    pd = T.load(T.xplane_file(TRACE_DIR))
+    pd = T.load(T.xplane_file(trace_dir))
     clock = T.host_marks(pd, T.CLOCK_MARK)[0][0]
     shift = clock - clock_pc
     spans = [(s + shift, e + shift, n) for s, e, n in host_spans]
@@ -283,7 +288,6 @@ def _reduce_trace(host_spans, clock_pc: float, rehearse: bool) -> dict:
         out = T.reduce(pd, spans, plane_prefix="/host:CPU", line_name=None)
     else:
         out = T.reduce(pd, spans)
-    shutil.rmtree(TRACE_DIR, ignore_errors=True)
     _log(f"trace: {out['n_ops']} device operations, busy {out['busy_s']!r} s "
          f"of {out['window_s']!r} s on {out['chips']} chip(s)")
     return out

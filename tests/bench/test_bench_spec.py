@@ -57,11 +57,66 @@ def test_unknown_names_raise():
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
 def test_config_knob_table_is_the_program_space(entry):
-    """The configuration states the 60-knob space the program tunes, and
-    its ``reduced`` keys are the entry's."""
-    from repro.sparksim import spark_space
+    """The space the configuration states resolves to a knob space of the
+    program with the stated number of knobs, and its ``reduced`` keys are
+    the entry's."""
+    from perfbench.lib.setup_data import space_of
 
     with open(os.path.join(spec.ROOT, entry["file"])) as f:
         cfg = json.load(f)
-    assert cfg["space"] == {"name": "spark_space", "knobs": len(spark_space().knobs)}
+    space = space_of(cfg)
+    assert space.dim == cfg["space"]["knobs"] == len(set(space.names))
     assert set(entry["reduced"]) <= set(cfg) and cfg["reduced"] == entry["reduced"]
+
+
+def _cfg(**changes):
+    cfg = spec.config(BENCH, "tpch100_F")
+    cfg.update(changes)
+    return cfg
+
+
+@pytest.mark.parametrize("name", ["no_such_space", "_private", "SCENARIOS",
+                                  "SparkWorkload", "make_task_id",
+                                  "build_knowledge_base"])
+def test_unknown_space_name_raises(name, monkeypatch):
+    """A name that ``repro.sparksim`` does not export as a knob space; no
+    other callable it exports is called."""
+    import functools
+    import inspect
+
+    import repro.sparksim
+    from perfbench.lib.setup_data import space_of
+
+    called = []
+    real = getattr(repro.sparksim, name, None)
+    if inspect.isfunction(real):
+        @functools.wraps(real)
+        def spy(*args, **kwargs):
+            called.append(name)
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(repro.sparksim, name, spy)
+    with pytest.raises(KeyError):
+        space_of(_cfg(space={"name": name, "knobs": 60}))
+    assert called == []
+
+
+def test_space_of_another_knob_count_raises():
+    from perfbench.lib.setup_data import space_of
+
+    with pytest.raises(ValueError):
+        space_of(_cfg(space={"name": "spark_space", "knobs": 61}))
+
+
+@pytest.mark.parametrize("change", [{"trees": 20}, {"max_depth": 8},
+                                    {"min_samples_split": 2}, {"bootstrap": False},
+                                    {"kind": "gaussian_process"}])
+def test_space_of_another_surrogate_raises(change):
+    """Every forest the program grows is the PRF at its defaults, so a
+    configuration that states another surrogate is refused."""
+    from perfbench.lib.setup_data import space_of
+
+    cfg = _cfg()
+    cfg["surrogate"] = {**cfg["surrogate"], **change}
+    with pytest.raises(ValueError):
+        space_of(cfg)
