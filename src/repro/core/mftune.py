@@ -305,7 +305,7 @@ class MFTune:
         if not self.opt.enable_sc:
             return
         t0 = _time.perf_counter()
-        with obs.span("space_compression") as sp:
+        with obs.span("space_compression", knobs=self.space.dim) as sp:
             tasks = {t.task_id: t for t in self.kb.source_tasks(self.target.task_id)}
             if self.opt.compressor is not None:
                 compressed = self.opt.compressor(
@@ -315,7 +315,7 @@ class MFTune:
                 compressed = self.compressor.compress(weights, tasks, target=self.target)
             if len(compressed) > 0:
                 self.gen.set_sample_space(compressed)
-            sp.set(knobs=len(compressed))
+            sp.set(kept=len(compressed))
         self._charge_overhead("space_compression", t0)
 
     def _try_partition(self, weights: TaskWeights) -> None:

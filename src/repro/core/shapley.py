@@ -158,8 +158,8 @@ def _chain_deltas_batched(
     When ``model`` is a packed-forest surrogate the chains are evaluated by
     the bitvector chain kernel (``kernels.forest_eval.chain``) — no
     composite tensor, ~1 word-AND per row instead of a gather descent.
-    Otherwise (or when the kernel doesn't apply: a tree with > 64 leaves,
-    d > 64) this builds the (chains x (d+1) prefixes x background)
+    Otherwise (or when the kernel doesn't apply: a tree with more than
+    128 leaves) this builds the (chains x (d+1) prefixes x background)
     composite tensor and evaluates it through ``f`` in calls of at most
     ``max_eval_rows`` rows (never splitting a chain), so one forest pass
     covers many chains while peak memory stays bounded. Per-row model
@@ -190,26 +190,28 @@ def _chain_deltas_batched(
         "pallas" if getattr(model, "backend", None) == "pallas" else "numpy"
     )
 
-    for a in range(0, n * P, chains_per_call):
-        b = min(a + chains_per_call, n * P)
-        if plan is not None:
-            vals[a:b] = plan.eval_chains(
-                X, background, flat_perms[a:b], x_of_chain[a:b],
-                backend=chain_backend,
+    span = "shapley_chain" if plan is not None else "shapley_composite"
+    with _obs.span(span, chains=n * P, knobs=d, background=nb):
+        for a in range(0, n * P, chains_per_call):
+            b = min(a + chains_per_call, n * P)
+            if plan is not None:
+                vals[a:b] = plan.eval_chains(
+                    X, background, flat_perms[a:b], x_of_chain[a:b],
+                    backend=chain_backend,
+                )
+                continue
+            masks = _prefix_masks_batch(flat_perms[a:b])          # (C, d+1, d)
+            C = b - a
+            M = np.broadcast_to(masks[:, :, None, :], (C, d + 1, nb, d))
+            Z = np.broadcast_to(background[None, None, :, :], (C, d + 1, nb, d)).copy()
+            Xb = np.broadcast_to(
+                X[x_of_chain[a:b], None, None, :], (C, d + 1, nb, d)
             )
-            continue
-        masks = _prefix_masks_batch(flat_perms[a:b])          # (C, d+1, d)
-        C = b - a
-        M = np.broadcast_to(masks[:, :, None, :], (C, d + 1, nb, d))
-        Z = np.broadcast_to(background[None, None, :, :], (C, d + 1, nb, d)).copy()
-        Xb = np.broadcast_to(
-            X[x_of_chain[a:b], None, None, :], (C, d + 1, nb, d)
-        )
-        Z[M] = Xb[M]
-        out = f(Z.reshape(C * (d + 1) * nb, d))
-        # same per-chain reduction as _eval_masked: mean over the background
-        # rows of each (chain, prefix) block
-        vals[a:b] = np.asarray(out).reshape(C, d + 1, nb).mean(axis=2)
+            Z[M] = Xb[M]
+            out = f(Z.reshape(C * (d + 1) * nb, d))
+            # same per-chain reduction as _eval_masked: mean over the
+            # background rows of each (chain, prefix) block
+            vals[a:b] = np.asarray(out).reshape(C, d + 1, nb).mean(axis=2)
     deltas = vals[:, 1:] - vals[:, :-1]
     return deltas.reshape(n, P, d)
 

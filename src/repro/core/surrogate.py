@@ -123,7 +123,7 @@ def _feature_subsets(seeds: np.ndarray, d: int, k: int) -> np.ndarray:
     k vectorized swap steps replace one ``Generator`` construction plus a
     ``permutation`` call *per node* — the dominant Python cost of a frontier
     level. Deterministic in the node seed and shared by both builders
-    (modulo bias at d <= 64 vs 2^64 states is negligible).
+    (modulo bias at d in the hundreds vs 2^64 states is negligible).
     """
     seeds = np.asarray(seeds, dtype=np.uint64)
     W = len(seeds)

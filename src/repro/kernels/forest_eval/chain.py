@@ -35,10 +35,11 @@ the fused propose step's merged QuickScorer plan
 :func:`build_false_tables`.
 
 ``build_chain_plan_ex`` returns ``(plan, reason)`` — ``(None, why)`` when
-the encoding does not apply (a tree with more than 128 leaves, or more
-than 64 features); callers fall back to the generic composite-tensor
-path. Values must be NaN-free (threshold ranks come from
-``np.searchsorted``).
+the encoding does not apply (not a packable forest, or a tree with more
+than 128 leaves); callers fall back to the generic composite-tensor path.
+The feature count is not bounded: tables are kept per feature and chains
+walk their permutation by index, so no word holds a set of features.
+Values must be NaN-free (threshold ranks come from ``np.searchsorted``).
 """
 
 from __future__ import annotations
@@ -324,14 +325,12 @@ def build_chain_plan_ex(model, d: int) -> Tuple[Optional[ChainPlan], str]:
     """Build (and cache on the packed arena) a ChainPlan.
 
     Returns ``(plan, "")`` on success and ``(None, reason)`` when the
-    model is not a packable forest, a tree exceeds 64 * MAX_LEAF_WORDS
-    leaves, or d > 64 (prefix sets as mask bits).
+    model is not a packable forest or a tree exceeds 64 * MAX_LEAF_WORDS
+    leaves.
     """
     pf = _pack_of(model)
     if pf is None:
         return None, "not a packable forest"
-    if d > 64:
-        return None, f"d={d} > 64 prefix-mask bits"
     cached = getattr(pf, _PLAN_ATTR, None)
     if cached is not None and cached[0] == d:
         return cached[1], ""
@@ -486,8 +485,6 @@ def build_pool_plan_ex(arena, d: int) -> Tuple[Optional["PoolPlan"], str]:
     for attr in ("feat", "thr", "child", "mean", "var", "roots"):
         if not hasattr(arena, attr):
             return None, "not a packed arena"
-    if d > 64:
-        return None, f"d={d} > 64 prefix-mask bits"
     cached = getattr(arena, _POOL_PLAN_ATTR, None)
     if cached is not None and cached[0] == d:
         return cached[1], cached[2]

@@ -1,5 +1,10 @@
-"""The 60-knob Spark SQL configuration space (paper §7.1: Tuneful's space
-extended to 60 performance-relevant parameters).
+"""The Spark SQL configuration spaces.
+
+``spark_space`` is the paper's 60-knob space (§7.1: Tuneful's space
+extended to 60 performance-relevant parameters). ``spark35_space`` is those
+60 knobs, in order, then 68 further settings documented for Apache Spark
+3.5 (``configuration.html`` and ``sql-performance-tuning.html``): a
+128-knob space of the width a Spark 3.5 warehouse exposes.
 
 Roughly a third of the knobs drive the cost model strongly (the realistic
 regime — most Spark knobs barely matter for a given workload, which is
@@ -12,7 +17,8 @@ from __future__ import annotations
 
 from ..core.space import BoolKnob, CatKnob, ConfigSpace, FloatKnob, IntKnob
 
-__all__ = ["spark_space", "INFLUENTIAL_KNOBS"]
+__all__ = ["spark_space", "spark35_space", "INFLUENTIAL_KNOBS",
+           "SPARK35_INFLUENTIAL_KNOBS", "SPARK35_DEFAULTS"]
 
 
 # knobs the cost model gives first-order effects to
@@ -113,4 +119,124 @@ def spark_space() -> ConfigSpace:
         BoolKnob("spark.sql.cbo.enabled", default=False),
     ]
     assert len(knobs) == 60, f"expected 60 knobs, got {len(knobs)}"
+    return ConfigSpace(knobs)
+
+
+# the knobs ``spark35_space`` adds to ``spark_space``: each with its name,
+# default and unit as Spark 3.5 documents them (sizes in the unit of the
+# comment, times in seconds unless marked), and a range a tuner would search
+_SPARK35_ADDED = [
+    # ---- configuration.html, Shuffle Behavior
+    IntKnob("spark.reducer.maxReqsInFlight", 1, 2147483647, log=True, default=2147483647),
+    IntKnob("spark.reducer.maxBlocksInFlightPerAddress", 1, 2147483647, log=True, default=2147483647),
+    IntKnob("spark.network.maxRemoteBlockSizeFetchToMem", 16, 2048, log=True, default=200),   # MB
+    IntKnob("spark.shuffle.unsafe.file.output.buffer", 16, 1024, log=True, default=32),       # KB
+    IntKnob("spark.shuffle.spill.diskWriteBufferSize", 256, 8192, log=True, default=1024),    # KB
+    IntKnob("spark.shuffle.io.maxRetries", 0, 10, default=3),
+    IntKnob("spark.shuffle.io.retryWait", 1, 30, default=5),
+    BoolKnob("spark.shuffle.io.preferDirectBufs", default=True),
+    IntKnob("spark.shuffle.service.index.cache.size", 16, 1024, log=True, default=100),       # MB
+    IntKnob("spark.shuffle.accurateBlockThreshold", 10, 1000, log=True, default=100),         # MB
+    BoolKnob("spark.shuffle.reduceLocality.enabled", default=True),
+    IntKnob("spark.shuffle.mapOutput.minSizeForBroadcast", 64, 8192, log=True, default=512),  # KB
+    BoolKnob("spark.shuffle.detectCorrupt", default=True),
+    BoolKnob("spark.shuffle.detectCorrupt.useExtraMemory", default=False),
+    BoolKnob("spark.shuffle.readHostLocalDisk", default=True),
+    BoolKnob("spark.shuffle.checksum.enabled", default=True),
+    CatKnob("spark.shuffle.mapStatus.compression.codec", ("lz4", "lzf", "snappy", "zstd"), default="zstd"),
+    # ---- configuration.html, Compression and Serialization
+    IntKnob("spark.io.compression.snappy.blockSize", 8, 128, log=True, default=32),           # KB
+    IntKnob("spark.io.compression.zstd.bufferSize", 8, 256, log=True, default=32),            # KB
+    BoolKnob("spark.kryo.referenceTracking", default=True),
+    BoolKnob("spark.kryo.unsafe", default=False),
+    IntKnob("spark.kryoserializer.buffer", 16, 1024, log=True, default=64),                   # KB
+    IntKnob("spark.serializer.objectStreamReset", 10, 1000, log=True, default=100),
+    # ---- configuration.html, Memory Management
+    IntKnob("spark.storage.unrollMemoryThreshold", 256, 16384, log=True, default=1024),       # KB
+    BoolKnob("spark.storage.replication.proactive", default=False),
+    BoolKnob("spark.cleaner.referenceTracking", default=True),
+    BoolKnob("spark.cleaner.referenceTracking.blocking", default=True),
+    BoolKnob("spark.cleaner.referenceTracking.blocking.shuffle", default=False),
+    # ---- configuration.html, Execution Behavior
+    BoolKnob("spark.broadcast.checksum", default=True),
+    IntKnob("spark.executor.heartbeatInterval", 5, 60, default=10),
+    IntKnob("spark.files.fetchTimeout", 30, 300, default=60),
+    BoolKnob("spark.files.useFetchCache", default=True),
+    # ---- configuration.html, Scheduling
+    IntKnob("spark.locality.wait.process", 0, 10, default=3),
+    IntKnob("spark.scheduler.maxRegisteredResourcesWaitingTime", 1, 120, default=30),
+    CatKnob("spark.scheduler.mode", ("FIFO", "FAIR"), default="FIFO"),
+    IntKnob("spark.speculation.interval", 10, 1000, log=True, default=100),                   # ms
+    IntKnob("spark.speculation.minTaskRuntime", 10, 10000, log=True, default=100),            # ms
+    IntKnob("spark.task.cpus", 1, 4, default=1),
+    # ---- configuration.html, Dynamic Allocation (maxExecutors: infinity,
+    # which Spark holds as Int.MaxValue)
+    BoolKnob("spark.dynamicAllocation.enabled", default=False),
+    IntKnob("spark.dynamicAllocation.minExecutors", 0, 24, default=0),
+    IntKnob("spark.dynamicAllocation.maxExecutors", 2, 2147483647, log=True, default=2147483647),
+    IntKnob("spark.dynamicAllocation.executorIdleTimeout", 10, 600, log=True, default=60),
+    IntKnob("spark.dynamicAllocation.schedulerBacklogTimeout", 1, 30, default=1),
+    FloatKnob("spark.dynamicAllocation.executorAllocationRatio", 0.1, 1.0, default=1.0),
+    # ---- sql-performance-tuning.html, Adaptive Query Execution
+    IntKnob("spark.sql.adaptive.advisoryPartitionSizeInBytes", 16, 1024, log=True, default=64),   # MB
+    IntKnob("spark.sql.adaptive.coalescePartitions.minPartitionSize", 1, 64, log=True, default=1),  # MB
+    BoolKnob("spark.sql.adaptive.coalescePartitions.parallelismFirst", default=True),
+    BoolKnob("spark.sql.adaptive.localShuffleReader.enabled", default=True),
+    IntKnob("spark.sql.adaptive.maxShuffledHashJoinLocalMapThreshold", 0, 512, default=0),    # MB
+    FloatKnob("spark.sql.adaptive.skewJoin.skewedPartitionFactor", 1.5, 20.0, log=True, default=5.0),
+    IntKnob("spark.sql.adaptive.skewJoin.skewedPartitionThresholdInBytes", 32, 2048, log=True, default=256),  # MB
+    BoolKnob("spark.sql.adaptive.forceOptimizeSkewedJoin", default=False),
+    BoolKnob("spark.sql.adaptive.optimizeSkewsInRebalancePartitions.enabled", default=True),
+    FloatKnob("spark.sql.adaptive.rebalancePartitionsSmallPartitionFactor", 0.05, 0.5, default=0.2),
+    # ---- configuration.html, Runtime SQL Configuration: optimizer
+    BoolKnob("spark.sql.optimizer.dynamicPartitionPruning.enabled", default=True),
+    BoolKnob("spark.sql.optimizer.runtime.bloomFilter.enabled", default=True),
+    IntKnob("spark.sql.optimizer.runtime.bloomFilter.creationSideThreshold", 1, 320, log=True, default=10),  # MB
+    BoolKnob("spark.sql.cbo.joinReorder.enabled", default=False),
+    IntKnob("spark.sql.cbo.joinReorder.dp.threshold", 4, 24, default=12),
+    BoolKnob("spark.sql.cbo.starSchemaDetection", default=False),
+    BoolKnob("spark.sql.statistics.histogram.enabled", default=False),
+    BoolKnob("spark.sql.bucketing.coalesceBucketsInJoin.enabled", default=False),
+    # ---- sql-performance-tuning.html and Runtime SQL Configuration: file sources
+    IntKnob("spark.sql.files.openCostInBytes", 1, 64, log=True, default=4),                   # MB
+    BoolKnob("spark.sql.parquet.enableVectorizedReader", default=True),
+    BoolKnob("spark.sql.parquet.filterPushdown", default=True),
+    IntKnob("spark.sql.parquet.columnarReaderBatchSize", 512, 32768, log=True, default=4096),
+    BoolKnob("spark.sql.inMemoryColumnarStorage.enableVectorizedReader", default=True),
+    IntKnob("spark.sql.sources.parallelPartitionDiscovery.parallelism", 100, 100000, log=True, default=10000),
+]
+
+# the added knobs the cost model reads (``model._added_factors``): first-
+# order effects, then the second-order knobs that shape them; every other
+# added knob is long tail
+SPARK35_INFLUENTIAL_KNOBS = [
+    "spark.sql.parquet.enableVectorizedReader",
+    "spark.sql.parquet.filterPushdown",
+    "spark.sql.files.openCostInBytes",
+    "spark.sql.optimizer.dynamicPartitionPruning.enabled",
+    "spark.sql.optimizer.runtime.bloomFilter.enabled",
+    "spark.sql.adaptive.advisoryPartitionSizeInBytes",
+    "spark.sql.adaptive.coalescePartitions.minPartitionSize",
+    "spark.sql.adaptive.coalescePartitions.parallelismFirst",
+    "spark.sql.adaptive.localShuffleReader.enabled",
+    "spark.sql.adaptive.skewJoin.skewedPartitionFactor",
+    "spark.sql.adaptive.skewJoin.skewedPartitionThresholdInBytes",
+    "spark.dynamicAllocation.enabled",
+    "spark.dynamicAllocation.minExecutors",
+    "spark.dynamicAllocation.maxExecutors",
+    "spark.reducer.maxReqsInFlight",
+    "spark.sql.optimizer.runtime.bloomFilter.creationSideThreshold",
+    "spark.dynamicAllocation.executorIdleTimeout",
+    "spark.dynamicAllocation.schedulerBacklogTimeout",
+    "spark.dynamicAllocation.executorAllocationRatio",
+]
+
+# the documented default of every added knob: the cost model reads an
+# added knob that is absent, or at this value, as having no effect at all
+SPARK35_DEFAULTS = {k.name: k.default_value() for k in _SPARK35_ADDED}
+
+
+def spark35_space() -> ConfigSpace:
+    knobs = spark_space().knobs + list(_SPARK35_ADDED)
+    assert len(knobs) == 128, f"expected 128 knobs, got {len(knobs)}"
     return ConfigSpace(knobs)

@@ -17,6 +17,17 @@ so the paper's *phenomena* hold structurally:
 - oversized executor heaps pay superlinear GC; undersized ones spill then
   OOM => the spark.executor.memory discussion in §1.
 
+The knobs ``spark35_space`` adds to ``spark_space`` act only where Spark 3.5
+documents them to: the Parquet reader, file packing, dynamic partition
+pruning and runtime bloom filters on the scan and shuffle bytes of
+join queries, AQE's partition sizing and skew splitting, dynamic allocation
+on the executor count, and fetch concurrency (``_added_factors``); the rest
+join the long tail. Their magnitudes are sparksim's own. An added knob that
+is absent from a config, or at its documented default, has no effect at
+all: every factor is then exactly 1.0 (or an exact 0.0 term), and the
+noise key leaves it out, so a ``spark_space`` config and the same config
+with every added knob at its default get the same latencies bit for bit.
+
 All stochasticity is multiplicative lognormal noise seeded per
 (task, config, query): repeated evaluation of a config is deterministic.
 The lognormal draw is derived from a blake2b hash of the cell identity via
@@ -38,10 +49,13 @@ Two evaluation paths share the model:
 from __future__ import annotations
 
 import hashlib
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
+
+from .knobs import SPARK35_DEFAULTS, SPARK35_INFLUENTIAL_KNOBS
 
 __all__ = ["HardwareScenario", "QueryProfile", "SparkCostModel", "SCENARIOS"]
 
@@ -141,6 +155,30 @@ CODEC = {  # (compression ratio, cpu overhead factor)
     "zstd": (0.38, 1.12),
 }
 
+# magnitudes of the Spark 3.5 knobs' effects (sparksim's, not measured)
+ROW_READER_SCAN = 1.30       # scan cost without the vectorized Parquet reader
+NO_PUSHDOWN_SCAN = 1.12      # scan cost without Parquet filter pushdown
+DPP_OFF_SCAN = 1.25          # scanned bytes of a join query without DPP
+BLOOM_SHUFFLE = 0.90         # shuffle bytes kept where a runtime bloom filter prunes
+BLOOM_BASE_MB = 10.0         # the documented creation-side threshold
+NO_LOCAL_READER_NET = 1.20   # network time of a broadcast-converted join without it
+SKEW_RESIDUAL = 0.35         # skew left after AQE splits skewed partitions
+DA_EXECUTOR_START_S = 60.0   # idle-release churn: this over executorIdleTimeout s
+
+# the added knobs that only wiggle the latency (the long tail)
+_ADDED_LONG_TAIL = tuple(n for n in SPARK35_DEFAULTS if n not in SPARK35_INFLUENTIAL_KNOBS)
+
+
+def _changed(cfg: "Config", name: str):
+    """An added knob's value, or None when absent or at its default."""
+    v = cfg.get(name)
+    return None if v is None or v == SPARK35_DEFAULTS[name] else v
+
+
+def _added(cfg: "Config", name: str):
+    """An added knob's value, its default when absent."""
+    return cfg.get(name, SPARK35_DEFAULTS[name])
+
 
 class SparkCostModel:
     def __init__(
@@ -181,8 +219,20 @@ class SparkCostModel:
         overhead_gb = float(cfg["spark.executor.memoryOverhead"]) / 1024.0
         per_node_by_cores = hw.cores // max(cores, 1)
         per_node_by_mem = int((hw.ram_gb * 0.92) // max(mem + overhead_gb, 0.5))
+        requested = int(cfg["spark.executor.instances"])
+        if _changed(cfg, "spark.dynamicAllocation.enabled"):
+            # the backlog asks for every executor the cluster holds; dynamic
+            # allocation grants it scaled by the allocation ratio, within
+            # [minExecutors, maxExecutors]
+            cap = min(max(per_node_by_cores, 0), max(per_node_by_mem, 0)) * hw.nodes
+            ratio = float(_added(cfg, "spark.dynamicAllocation.executorAllocationRatio"))
+            requested = max(
+                int(_added(cfg, "spark.dynamicAllocation.minExecutors")),
+                min(int(_added(cfg, "spark.dynamicAllocation.maxExecutors")),
+                    int(math.ceil(ratio * cap))),
+            )
         launched = min(
-            int(cfg["spark.executor.instances"]),
+            requested,
             max(per_node_by_cores, 0) * hw.nodes,
             max(per_node_by_mem, 0) * hw.nodes,
         )
@@ -200,6 +250,72 @@ class SparkCostModel:
         task_mem = (usable + 0.7 * offheap_gb) / max(cores, 1)
         return launched, slots, task_mem
 
+    def _added_factors(self, cfg: Config, launched: int) -> Dict[str, float]:
+        """Per-config factors of the knobs ``spark35_space`` adds, each
+        exactly neutral (1.0, 0.0, or off) where its knobs are absent or at
+        their defaults. Shared by the scalar and the batched path, so both
+        read the same floats."""
+        scan_mult = 1.0
+        if _changed(cfg, "spark.sql.parquet.enableVectorizedReader") is not None:
+            scan_mult *= ROW_READER_SCAN
+        if _changed(cfg, "spark.sql.parquet.filterPushdown") is not None:
+            scan_mult *= NO_PUSHDOWN_SCAN
+        open_cost = _changed(cfg, "spark.sql.files.openCostInBytes")
+        if open_cost is not None:
+            # an under-estimated open cost packs many small files into one
+            # split (slow); an over-estimate costs a little parallelism
+            r = float(open_cost) / SPARK35_DEFAULTS["spark.sql.files.openCostInBytes"]
+            scan_mult *= 1.0 + (0.03 * math.log2(1.0 / r) if r < 1.0 else 0.01 * math.log2(r))
+
+        bloom_on = bool(_added(cfg, "spark.sql.optimizer.runtime.bloomFilter.enabled"))
+        bloom_thr = float(_added(cfg, "spark.sql.optimizer.runtime.bloomFilter.creationSideThreshold"))
+        bloom_changed = (not bloom_on) or bloom_thr != BLOOM_BASE_MB
+
+        aqe = bool(cfg["spark.sql.adaptive.enabled"])
+        min_part = _changed(cfg, "spark.sql.adaptive.coalescePartitions.minPartitionSize")
+        adv = float(_added(cfg, "spark.sql.adaptive.advisoryPartitionSizeInBytes"))
+
+        skew_resid = SKEW_RESIDUAL
+        for name, power in (("spark.sql.adaptive.skewJoin.skewedPartitionFactor", 0.5),
+                            ("spark.sql.adaptive.skewJoin.skewedPartitionThresholdInBytes", 0.25),
+                            ("spark.sql.adaptive.advisoryPartitionSizeInBytes", 0.25)):
+            v = _changed(cfg, name)
+            if v is not None:
+                # lower split thresholds and targets split more of the skew
+                skew_resid *= (float(v) / SPARK35_DEFAULTS[name]) ** power
+        skew_resid = min(skew_resid, 1.0)
+
+        fetch_mult = 1.0
+        reqs = _changed(cfg, "spark.reducer.maxReqsInFlight")
+        if reqs is not None and reqs < 64:
+            fetch_mult = 1.0 + 0.05 * math.log2(64.0 / float(reqs))
+
+        da_overhead = 0.0
+        if _changed(cfg, "spark.dynamicAllocation.enabled"):
+            # executors are added in doubling rounds, one backlog timeout
+            # each, from minExecutors; a short idle timeout churns them
+            lo = min(int(_added(cfg, "spark.dynamicAllocation.minExecutors")), launched)
+            rounds = math.ceil(math.log2(launched - lo + 1))
+            backlog = float(_added(cfg, "spark.dynamicAllocation.schedulerBacklogTimeout"))
+            idle = float(_added(cfg, "spark.dynamicAllocation.executorIdleTimeout"))
+            da_overhead = backlog * (rounds + 1) + DA_EXECUTOR_START_S / idle
+
+        return {
+            "scan_mult": scan_mult,
+            "dpp_off": float(_changed(cfg, "spark.sql.optimizer.dynamicPartitionPruning.enabled") is not None),
+            "bloom_changed": float(bloom_changed),
+            "bloom_thr": bloom_thr if bloom_on else 0.0,
+            "pf_off": float(_changed(cfg, "spark.sql.adaptive.coalescePartitions.parallelismFirst") is not None),
+            "adv_gb": adv / 1024.0,
+            "min_part": float(min_part is not None),
+            "min_part_gb": float(min_part if min_part is not None else 1) / 1024.0,
+            "local_reader_off": float(
+                aqe and _changed(cfg, "spark.sql.adaptive.localShuffleReader.enabled") is not None),
+            "skew_resid": skew_resid,
+            "fetch_mult": fetch_mult,
+            "da_overhead": da_overhead,
+        }
+
     # ---------------------------------------------------------- query model
     def query_latency(
         self, cfg: Config, q: QueryProfile, data_fraction: float = 1.0
@@ -207,8 +323,12 @@ class SparkCostModel:
         """Return (latency_s, failed, latency breakdown)."""
         hw = self.hw
         E, slots, task_mem = self._executors(cfg)
+        fx = self._added_factors(cfg, E)
         data_gb = self.data_gb * float(np.clip(data_fraction, 1e-3, 1.0))
         scan_gb = q.scan_frac * data_gb
+        join = q.small_table_mb > 0
+        if fx["dpp_off"] and join:
+            scan_gb = scan_gb * DPP_OFF_SCAN
 
         eff_slots = max(min(slots, q.parallelism_ceiling * hw.nodes), 1)
 
@@ -219,7 +339,7 @@ class SparkCostModel:
         util = map_tasks / (waves * eff_slots)  # <=1; poor when few big tasks
         codec_ratio, codec_cpu = CODEC[cfg["spark.io.compression.codec"]]
         scan_time = (
-            scan_gb / (IO_BW_PER_SLOT * eff_slots * max(util, 1e-3)) * codec_cpu
+            scan_gb / (IO_BW_PER_SLOT * eff_slots * max(util, 1e-3)) * codec_cpu * fx["scan_mult"]
             + map_tasks * TASK_OVERHEAD / max(slots, 1)
         )
 
@@ -234,28 +354,49 @@ class SparkCostModel:
         # ---- shuffle
         shuffle_gb = q.shuffle_frac * scan_gb
         bcast_thresh = float(cfg["spark.sql.autoBroadcastJoinThreshold"])
-        if q.small_table_mb > 0 and bcast_thresh >= q.small_table_mb:
+        bcast = join and bcast_thresh >= q.small_table_mb
+        if bcast:
             shuffle_gb *= 1.0 - q.broadcast_benefit
+        if fx["bloom_changed"] and join:
+            # a runtime bloom filter prunes the probe side where the creation
+            # side fits its threshold: gained or lost against the default
+            was = q.small_table_mb <= BLOOM_BASE_MB
+            now = q.small_table_mb <= fx["bloom_thr"]
+            if now and not was:
+                shuffle_gb = shuffle_gb * BLOOM_SHUFFLE
+            elif was and not now:
+                shuffle_gb = shuffle_gb / BLOOM_SHUFFLE
         p = float(cfg["spark.sql.shuffle.partitions"])
         aqe = bool(cfg["spark.sql.adaptive.enabled"])
         if aqe and cfg["spark.sql.adaptive.coalescePartitions.enabled"]:
             # AQE coalesce pulls the effective partition count toward a
-            # data-derived target (128MB per partition)
+            # data-derived target (128MB per partition) that keeps the
+            # parallelism; without parallelismFirst it aims at the advisory
+            # size alone; a minimum partition size caps the count
             p_target = max(shuffle_gb / 0.125, eff_slots)
+            if fx["pf_off"]:
+                p_target = max(shuffle_gb / fx["adv_gb"], 1.0)
+            if fx["min_part"]:
+                p_target = min(p_target, max(shuffle_gb / fx["min_part_gb"], 1.0))
+            if fx["pf_off"] or fx["min_part"]:
+                p = min(p, p_target)  # coalescing merges down to the sized target
             p = np.clip(p, p_target * 0.75, None) if p > p_target else 0.5 * (p + p_target)
         skew = q.skew
         if aqe and cfg["spark.sql.adaptive.skewJoin.enabled"]:
-            skew = 1.0 + (skew - 1.0) * 0.35
+            skew = 1.0 + (skew - 1.0) * fx["skew_resid"]
         comp_on = bool(cfg["spark.shuffle.compress"])
         wire_gb = shuffle_gb * (codec_ratio if comp_on else 1.0)
         comp_cpu = codec_cpu if comp_on else 1.0
         net_time = 2.0 * wire_gb / (NET_BW_PER_NODE * hw.nodes)
+        if fx["local_reader_off"] and bcast:
+            net_time = net_time * NO_LOCAL_READER_NET
         per_part_gb = shuffle_gb * skew / max(p, 1.0)
         reduce_waves = np.ceil(p / eff_slots)
         fetch_eff = 1.0 + 0.04 * np.log2(48.0 / np.clip(float(cfg["spark.reducer.maxSizeInFlight"]), 8, 256))
         buf_eff = 1.0 + 0.03 * np.log2(64.0 / np.clip(float(cfg["spark.shuffle.file.buffer"]), 16, 1024))
         proc_time = (
             reduce_waves * per_part_gb / PROC_BW_PER_SLOT * comp_cpu * max(fetch_eff, 0.9) * max(buf_eff, 0.9)
+            * fx["fetch_mult"]
         )
         sched_time = p * TASK_OVERHEAD / max(slots, 1)
 
@@ -276,7 +417,7 @@ class SparkCostModel:
         loc_wait = float(cfg["spark.locality.wait"])
         tail += 0.004 * loc_wait * (waves + reduce_waves)
 
-        latency = (scan_time + compute_time + shuffle_time) * tail
+        latency = (scan_time + compute_time + shuffle_time) * tail + fx["da_overhead"]
 
         # ---- long-tail knobs: tiny deterministic per-(knob,value) wiggle
         latency *= self._minor_knob_factor(cfg)
@@ -307,6 +448,11 @@ class SparkCostModel:
         ):
             u = _stable_u32(name, repr(cfg.get(name))) / 2**32
             f *= 1.0 + (u - 0.5) * 0.004
+        for name in _ADDED_LONG_TAIL:
+            v = _changed(cfg, name)
+            if v is not None:
+                u = _stable_u32(name, repr(v)) / 2**32
+                f *= 1.0 + (u - 0.5) * 0.004
         return f
 
     # ------------------------------------------------------------- noise
@@ -365,7 +511,11 @@ class SparkCostModel:
 
     @staticmethod
     def _cfg_key(cfg: Config) -> str:
-        return repr(sorted((k, repr(v)) for k, v in cfg.items()))
+        """Noise key: every knob but the added ones at their defaults."""
+        return repr(sorted(
+            (k, repr(v)) for k, v in cfg.items()
+            if k not in SPARK35_DEFAULTS or v != SPARK35_DEFAULTS[k]
+        ))
 
     # ----------------------------------------------------- batched evaluation
     def _config_scalars(self, cfg: Config) -> Dict[str, float]:
@@ -402,6 +552,7 @@ class SparkCostModel:
             "speculation": float(bool(cfg["spark.speculation"])),
             "loc_wait": float(cfg["spark.locality.wait"]),
             "minor": self._minor_knob_factor(cfg),
+            **self._added_factors(cfg, E),
         }
 
     def _latency_grid(
@@ -420,6 +571,9 @@ class SparkCostModel:
             "codegen", "gc_pow", "bcast_thresh", "p0", "aqe_coalesce", "aqe_skew",
             "wire_factor", "comp_cpu", "fetch_eff", "buf_eff", "spill_gain",
             "speculation", "loc_wait", "minor",
+            "scan_mult", "dpp_off", "bloom_changed", "bloom_thr", "pf_off", "adv_gb",
+            "min_part", "min_part_gb", "local_reader_off", "skew_resid", "fetch_mult",
+            "da_overhead",
         )}
         slots_i = np.empty(C, dtype=np.int64)
         seeds = np.empty((C, Q), dtype=np.uint64)
@@ -435,45 +589,62 @@ class SparkCostModel:
 
         q = {k: v[idx] for k, v in self._q.items()}
         data_gb = self.data_gb * float(np.clip(data_fraction, 1e-3, 1.0))
-        scan_gb = q["scan_frac"] * data_gb                               # (Q,)
+        join = q["small_table_mb"][None, :] > 0                         # (1, Q)
+        scan_gb = np.broadcast_to(q["scan_frac"] * data_gb, (C, Q))     # (C, Q)
+        scan_gb = np.where((col("dpp_off") > 0) & join, scan_gb * DPP_OFF_SCAN, scan_gb)
         slots = slots_i[:, None]                                         # (C, 1)
         eff_slots = np.maximum(
             np.minimum(slots, q["parallelism_ceiling"][None, :] * hw.nodes), 1
         )                                                                # (C, Q)
 
         # ---- scan (operation order mirrors query_latency exactly)
-        map_tasks = np.maximum(np.ceil(scan_gb[None, :] / col("mpb_gb_floor")), 1.0)
+        map_tasks = np.maximum(np.ceil(scan_gb / col("mpb_gb_floor")), 1.0)
         waves = np.ceil(map_tasks / eff_slots)
         util = map_tasks / (waves * eff_slots)
         scan_time = (
-            scan_gb[None, :] / (IO_BW_PER_SLOT * eff_slots * np.maximum(util, 1e-3)) * col("codec_cpu")
+            scan_gb / (IO_BW_PER_SLOT * eff_slots * np.maximum(util, 1e-3)) * col("codec_cpu")
+            * col("scan_mult")
             + map_tasks * TASK_OVERHEAD / np.maximum(slots, 1)
         )
 
         # ---- compute
         gc_factor = 1.0 + (0.05 * q["gc_sensitivity"])[None, :] * col("gc_pow")
         compute_time = (
-            (q["cpu_per_gb"] * scan_gb)[None, :] / eff_slots
+            q["cpu_per_gb"][None, :] * scan_gb / eff_slots
             * col("ser_factor") * col("codegen") * gc_factor
         )
 
         # ---- shuffle
-        shuffle_gb = np.broadcast_to((q["shuffle_frac"] * scan_gb)[None, :], (C, Q))
-        bcast = (q["small_table_mb"][None, :] > 0) & (col("bcast_thresh") >= q["small_table_mb"][None, :])
+        shuffle_gb = q["shuffle_frac"][None, :] * scan_gb
+        small = q["small_table_mb"][None, :]
+        bcast = join & (col("bcast_thresh") >= small)
         shuffle_gb = np.where(bcast, shuffle_gb * (1.0 - q["broadcast_benefit"])[None, :], shuffle_gb)
+        bloom = (col("bloom_changed") > 0) & join
+        was, now = small <= BLOOM_BASE_MB, small <= col("bloom_thr")
+        shuffle_gb = np.where(bloom & now & ~was, shuffle_gb * BLOOM_SHUFFLE,
+                              np.where(bloom & was & ~now, shuffle_gb / BLOOM_SHUFFLE, shuffle_gb))
         p = np.broadcast_to(col("p0"), (C, Q))
         p_target = np.maximum(shuffle_gb / 0.125, eff_slots)
+        p_target = np.where(col("pf_off") > 0,
+                            np.maximum(shuffle_gb / col("adv_gb"), 1.0), p_target)
+        p_target = np.where(col("min_part") > 0,
+                            np.minimum(p_target, np.maximum(shuffle_gb / col("min_part_gb"), 1.0)),
+                            p_target)
+        sized = ((col("pf_off") > 0) | (col("min_part") > 0)) & (col("aqe_coalesce") > 0)
+        p = np.where(sized, np.minimum(p, p_target), p)
         p_coalesced = np.where(p > p_target, p, 0.5 * (p + p_target))
         p = np.where(col("aqe_coalesce") > 0, p_coalesced, p)
         skew = np.broadcast_to(q["skew"][None, :], (C, Q))
-        skew = np.where(col("aqe_skew") > 0, 1.0 + (skew - 1.0) * 0.35, skew)
+        skew = np.where(col("aqe_skew") > 0, 1.0 + (skew - 1.0) * col("skew_resid"), skew)
         wire_gb = shuffle_gb * col("wire_factor")
         net_time = 2.0 * wire_gb / (NET_BW_PER_NODE * hw.nodes)
+        net_time = np.where((col("local_reader_off") > 0) & bcast,
+                            net_time * NO_LOCAL_READER_NET, net_time)
         per_part_gb = shuffle_gb * skew / np.maximum(p, 1.0)
         reduce_waves = np.ceil(p / eff_slots)
         proc_time = (
             reduce_waves * per_part_gb / PROC_BW_PER_SLOT * col("comp_cpu")
-            * col("fetch_eff") * col("buf_eff")
+            * col("fetch_eff") * col("buf_eff") * col("fetch_mult")
         )
         sched_time = p * TASK_OVERHEAD / np.maximum(slots, 1)
 
@@ -491,7 +662,7 @@ class SparkCostModel:
         tail = np.where(col("speculation") > 0, 1.0 + (tail - 1.0) * 0.55, tail)
         tail = tail + 0.004 * col("loc_wait") * (waves + reduce_waves)
 
-        latency = (scan_time + compute_time + shuffle_time) * tail
+        latency = (scan_time + compute_time + shuffle_time) * tail + col("da_overhead")
         latency = latency * col("minor")
         latency = np.where(failed, TIMEOUT_FACTOR * latency, latency)
         latency = latency * self._lognormal_from_seeds(seeds)

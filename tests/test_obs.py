@@ -553,3 +553,59 @@ def test_lowered_step_names_every_stage(monkeypatch):
         text = orig.lower(*a, **kw).as_text(debug_info=True)
     for stage in ("draw", "descent", "combine", "ei", "rank", "topk"):
         assert f"/{stage}/" in text, stage
+
+
+def _compression_pass(d, tracer):
+    """One ``SpaceCompressor.compress`` over two source tasks in a ``d``-knob
+    space, under ``tracer``."""
+    from repro.core import ConfigSpace, FloatKnob, Observation, SpaceCompressor, TaskRecord
+    from repro.core.similarity import TaskWeights
+
+    space = ConfigSpace([FloatKnob(f"x{i}", 0.0, 1.0) for i in range(d)])
+    tasks = {}
+    for t in range(2):
+        rec = TaskRecord(task_id=f"s{t}", queries=["q1"])
+        for cfg in space.sample(np.random.default_rng(t), 40):
+            perf = (cfg["x0"] - 0.2) ** 2 + (cfg[f"x{d - 1}"] - 0.7) ** 2 + 1.0
+            rec.observations.append(Observation(config=cfg, performance=perf, fidelity=1.0))
+        tasks[rec.task_id] = rec
+    weights = TaskWeights(weights={k: 0.5 for k in tasks}, similarities={}, used_meta=False)
+    with obs.tracing(tracer):
+        SpaceCompressor(space, alpha=0.65, seed=0).compress(weights, tasks)
+
+
+def test_shapley_chain_spans_past_64_knobs():
+    tr = obs.Tracer("compress")
+    _compression_pass(128, tr)
+    spans = _spans(tr)
+    by_id = {s["id"]: s for s in spans}
+    chain = [s for s in spans if s["name"] == "shapley_chain"]
+    assert chain and not [s for s in spans if s["name"] == "shapley_composite"]
+    for s in chain:
+        assert by_id[s["parent"]]["name"] == "shapley_attribution"
+        assert s["args"]["knobs"] == 128 and s["args"]["chains"] > 0
+    counts = tr.metrics.counters_view("shapley/")
+    assert counts["chain_kernel"] == len(chain) and "composite_fallback" not in counts
+
+
+def test_shapley_composite_span_on_leaf_overflow():
+    from repro.core import ProbabilisticRandomForest, draw_permutations, shapley_values_batch
+
+    rng = np.random.default_rng(0)
+    m = ProbabilisticRandomForest(seed=0, n_trees=3, max_depth=12,
+                                  min_samples_split=2).fit(rng.random((600, 4)), rng.random(600))
+    tr = obs.Tracer("overflow")
+    with obs.tracing(tr):
+        shapley_values_batch(m.predict_mean, rng.random((2, 4)), rng.random((5, 4)),
+                             perms=draw_permutations(4, 4, rng), model=m)
+    names = [s["name"] for s in _spans(tr)]
+    assert names.count("shapley_composite") == 1 and "shapley_chain" not in names
+    assert tr.metrics.counters_view("shapley/") == {"composite_fallback": 1}
+
+
+def test_space_compression_span_names_the_width():
+    _, _, _, tracer = _identity_run(traced=True)
+    comp = [s for s in _spans(tracer) if s["name"] == "space_compression"]
+    assert comp
+    for s in comp:
+        assert s["args"]["knobs"] == 60 and 0 < s["args"]["kept"] <= 60

@@ -55,6 +55,25 @@ def test_delta_predict_bit_identical():
     np.testing.assert_array_equal(v0, v1)
 
 
+@pytest.mark.parametrize("d", [65, 128, 200])
+def test_delta_predict_past_64_features(d):
+    """The pool plan builds at any width and its delta scoring equals the
+    plain descent bit for bit."""
+    from repro.kernels.forest_eval.chain import build_pool_plan_ex
+
+    plane, rng = _plane(d=d, seed=d, depth=8)
+    plan, reason = build_pool_plan_ex(plane, d)
+    assert plan is not None and reason == ""
+    assert max(int(f) for f in plane.feat) >= 64
+    X, bases, base_of = _mutation_pool(rng, d=d)
+    m0, v0 = plane.predict(X, backend="numpy")
+    with obs.tracing() as tr:
+        m1, v1 = plane.predict(X, backend="numpy", delta=(bases, base_of))
+    assert tr.metrics.counters_view("forest_plane/").get("chain_delta", 0) == 1
+    np.testing.assert_array_equal(m0, m1)
+    np.testing.assert_array_equal(v0, v1)
+
+
 def test_delta_predict_degenerate_pools():
     plane, rng = _plane(seed=12)
     X, bases, base_of = _mutation_pool(rng, d=6)

@@ -377,7 +377,8 @@ def test_chain_plan_guards():
     from repro.kernels.forest_eval.chain import build_chain_plan
 
     m = _forest(5, seed=1)
-    assert build_chain_plan(m, 70) is None  # prefix masks need d <= 64
+    assert max(int(f) for f in m.pack().feat) >= 3
+    assert build_chain_plan(m, 3) is None  # splits outside a 3-dim space
     assert build_chain_plan(object(), 5) is None  # not a packable forest
     # model= on a non-forest callable silently uses the generic path
     f = _poly(5, seed=2)
@@ -389,6 +390,36 @@ def test_chain_plan_guards():
         backend="batched", model=object(),
     )
     assert np.array_equal(a, b)
+
+
+def _wide_forest(d, seed=0, n=200):
+    """A forest over ``d`` features whose signal lies past the 64th."""
+    rng = np.random.default_rng(seed)
+    X = rng.random((n, d))
+    y = 3 * X[:, d - 1] - X[:, d // 2] ** 2 + X[:, 0] + 0.1 * rng.normal(size=n)
+    return make_forest(seed=seed).fit(X, y)
+
+
+@pytest.mark.parametrize("d", [65, 128, 200])
+def test_chain_kernel_past_64_features(d):
+    """The chain plan keeps per-feature tables and walks permutations by
+    index, so it builds at any width and stays bit-identical to the loop."""
+    from repro import obs
+    from repro.kernels.forest_eval.chain import build_chain_plan_ex
+
+    m = _wide_forest(d, seed=d)
+    plan, reason = build_chain_plan_ex(m, d)
+    assert plan is not None and reason == ""
+    assert max(int(f) for f in m.pack().feat) >= 64  # splits past one word
+    rng = np.random.default_rng(d + 1)
+    X, bg = rng.random((3, d)), rng.random((6, d))
+    perms = draw_permutations(d, 4, rng)
+    loop = shapley_values_batch(m.predict_mean, X, bg, perms=perms, backend="loop")
+    with obs.tracing() as tr:
+        chained = shapley_values_batch(m.predict_mean, X, bg, perms=perms, model=m)
+    assert np.array_equal(loop, chained)
+    counts = tr.metrics.counters_view("shapley/")
+    assert counts.get("chain_kernel", 0) == 1 and "composite_fallback" not in counts
 
 
 # ------------------------------------------------- end-to-end backend identity

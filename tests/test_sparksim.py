@@ -109,3 +109,184 @@ def test_hardware_scenarios_differ(wl):
     a = SparkWorkload("tpch", 600, "A").evaluate(cfg).aggregate
     f = SparkWorkload("tpch", 600, "F").evaluate(cfg).aggregate
     assert f > a  # scenario F (2 nodes, 32 cores, 128GB) is strictly smaller
+
+
+# ------------------------------------------------ the 128-knob Spark 3.5 space
+
+def test_spark35_space_extends_spark_space():
+    from repro.sparksim import SPARK35_DEFAULTS, SPARK35_INFLUENTIAL_KNOBS, spark35_space
+
+    wide, base = spark35_space(), spark_space()
+    assert len(wide) == 128 and len(set(wide.names)) == 128
+    assert wide.names[:60] == base.names
+    assert [k.default_value() for k in wide.knobs[:60]] == [k.default_value() for k in base.knobs]
+    assert list(SPARK35_DEFAULTS) == wide.names[60:]
+    assert set(SPARK35_INFLUENTIAL_KNOBS) <= set(SPARK35_DEFAULTS)
+    assert wide.default() == dict(base.default(), **SPARK35_DEFAULTS)
+
+
+# blake2b-16 digests of the per-query latencies of eight ``spark_space``
+# configs drawn from seed 20260117, as the cost model gave them before the
+# Spark 3.5 knobs existed (scalar path; two of the TPC-DS configs and one
+# of the TPC-H ones fail)
+PINNED_SPARK_SPACE = {
+    ("tpcds", 600, "A"): "4958f031e34876ed0c4fcc090668784a",
+    ("tpch", 100, "F"): "04613becc6222c12355d6327952de98f",
+}
+
+
+@pytest.mark.parametrize("task", sorted(PINNED_SPARK_SPACE))
+def test_spark_space_latencies_unchanged(task):
+    import hashlib
+
+    space = spark_space()
+    cfgs = [dict(c) for c in space.sample(np.random.default_rng(20260117), 8)]
+    w = SparkWorkload(*task)
+    scalar, batched = (hashlib.blake2b(digest_size=16) for _ in range(2))
+    for cfg, many in zip(cfgs, w.evaluate_many(cfgs)):
+        scalar.update(np.asarray(w.evaluate(cfg).per_query_latency).tobytes())
+        batched.update(np.asarray(many.per_query_latency).tobytes())
+    assert scalar.hexdigest() == batched.hexdigest() == PINNED_SPARK_SPACE[task]
+
+
+def test_spark35_defaults_match_spark_space_on_every_task():
+    from repro.sparksim import all_task_specs, spark35_space
+
+    base, wide = spark_space(), spark35_space()
+    for spec in all_task_specs():
+        args = (spec.benchmark, spec.data_gb, spec.hardware)
+        a = SparkWorkload(*args, space=base).evaluate(base.default())
+        b = SparkWorkload(*args, space=wide).evaluate(wide.default())
+        assert a.per_query_latency == b.per_query_latency, spec.task_id
+
+
+@pytest.mark.parametrize("task", [("tpcds", 600, "A"), ("tpch", 100, "F")])
+def test_spark35_scalar_matches_batched(task):
+    from repro.sparksim import spark35_space
+
+    space = spark35_space()
+    w = SparkWorkload(*task, space=space)
+    cfgs = [dict(c) for c in space.sample(np.random.default_rng(11), 48)]
+    # every first-order effect switched on at least once
+    cfgs.append(dict(space.default(), **{
+        "spark.sql.parquet.enableVectorizedReader": False,
+        "spark.sql.optimizer.dynamicPartitionPruning.enabled": False,
+        "spark.sql.optimizer.runtime.bloomFilter.creationSideThreshold": 200,
+        "spark.sql.adaptive.coalescePartitions.parallelismFirst": False,
+        "spark.sql.adaptive.coalescePartitions.minPartitionSize": 16,
+        "spark.sql.adaptive.localShuffleReader.enabled": False,
+        "spark.sql.adaptive.skewJoin.enabled": True,
+        "spark.sql.adaptive.skewJoin.skewedPartitionFactor": 2.0,
+        "spark.dynamicAllocation.enabled": True,
+        "spark.reducer.maxReqsInFlight": 4,
+    }))
+    # sizing knobs set while AQE's coalescing is off: they must not act
+    cfgs.append(dict(space.default(), **{
+        "spark.sql.adaptive.coalescePartitions.enabled": False,
+        "spark.sql.adaptive.coalescePartitions.parallelismFirst": False,
+        "spark.sql.adaptive.coalescePartitions.minPartitionSize": 32,
+    }))
+    caps = [None] * len(cfgs)
+    caps[3] = 100.0
+    many = w.evaluate_many(cfgs, cost_cap=caps)
+    for cfg, cap, got in zip(cfgs, caps, many):
+        one = w.evaluate(cfg, cost_cap=cap)
+        assert one.per_query_latency == got.per_query_latency
+        assert one.per_query_cost == got.per_query_cost
+        assert (one.failed, one.failure_reason) == (got.failed, got.failure_reason)
+
+
+def _profile(**kw):
+    from repro.sparksim import QueryProfile
+
+    base = dict(name="q", scan_frac=0.05, shuffle_frac=0.6, cpu_per_gb=2.0,
+                mem_per_gb=0.5, skew=4.0, small_table_mb=0.0, broadcast_benefit=0.5,
+                parallelism_ceiling=300, oom_resilience=50.0, gc_sensitivity=0.5)
+    return QueryProfile(**dict(base, **kw))
+
+
+JOIN = dict(small_table_mb=100.0)       # a dimension join, not broadcast at 10 MB
+BCAST = dict(small_table_mb=8.0)        # broadcast under the 10 MB threshold
+SKEW_ON = {"spark.sql.adaptive.skewJoin.enabled": True}
+PF_OFF = {"spark.sql.adaptive.coalescePartitions.parallelismFirst": False}
+DA_ON = {"spark.dynamicAllocation.enabled": True, "spark.executor.instances": 4}
+
+
+# (knob, value, other changes, profile, what moves, direction): each
+# first-order knob, set away from its default on a query it applies to
+FIRST_ORDER = [
+    ("spark.sql.parquet.enableVectorizedReader", False, {}, {}, "latency", +1),
+    ("spark.sql.parquet.filterPushdown", False, {}, {}, "latency", +1),
+    ("spark.sql.files.openCostInBytes", 1, {}, {}, "latency", +1),
+    ("spark.sql.optimizer.dynamicPartitionPruning.enabled", False, {}, JOIN, "latency", +1),
+    ("spark.sql.optimizer.runtime.bloomFilter.enabled", False, {}, dict(small_table_mb=6.0),
+     "latency", +1),
+    ("spark.sql.optimizer.runtime.bloomFilter.creationSideThreshold", 200, {}, JOIN,
+     "latency", -1),
+    ("spark.sql.adaptive.advisoryPartitionSizeInBytes", 512, PF_OFF, {}, "spill_ratio", +1),
+    ("spark.sql.adaptive.coalescePartitions.minPartitionSize", 64, {}, dict(scan_frac=0.002),
+     "spill_ratio", +1),
+    ("spark.sql.adaptive.coalescePartitions.parallelismFirst", False, {}, dict(scan_frac=0.002),
+     "spill_ratio", +1),
+    ("spark.sql.adaptive.localShuffleReader.enabled", False, {}, BCAST, "latency", +1),
+    ("spark.sql.adaptive.skewJoin.skewedPartitionFactor", 2.0, SKEW_ON, {}, "latency", -1),
+    ("spark.sql.adaptive.skewJoin.skewedPartitionThresholdInBytes", 64, SKEW_ON, {}, "latency", -1),
+    ("spark.dynamicAllocation.enabled", True, {"spark.executor.instances": 4}, {}, "executors", +1),
+    ("spark.dynamicAllocation.minExecutors", 20,
+     dict(DA_ON, **{"spark.dynamicAllocation.maxExecutors": 8}), {}, "executors", +1),
+    ("spark.dynamicAllocation.maxExecutors", 6, DA_ON, {}, "executors", -1),
+    ("spark.reducer.maxReqsInFlight", 4, {}, {}, "latency", +1),
+]
+
+
+@pytest.mark.parametrize("knob,value,given,prof,what,sign", FIRST_ORDER,
+                         ids=[f[0].split(".", 1)[1] for f in FIRST_ORDER])
+def test_spark35_first_order_direction(knob, value, given, prof, what, sign):
+    from repro.sparksim import SparkCostModel, spark35_space
+
+    model = SparkCostModel("tpcds", 600, SCENARIOS["A"])
+    q = _profile(**prof)
+    before = dict(spark35_space().default(), **given)
+    after = dict(before, **{knob: value})
+
+    def read(cfg):
+        lat, failed, bd = model.query_latency(cfg, q)
+        assert not failed
+        return lat if what == "latency" else bd[what]
+
+    assert np.sign(read(after) - read(before)) == sign
+
+
+def test_spark35_join_effects_leave_other_queries():
+    from repro.sparksim import SparkCostModel, spark35_space
+
+    model = SparkCostModel("tpcds", 600, SCENARIOS["A"])
+    q = _profile()  # no dimension join
+    cfg = spark35_space().default()
+    off = dict(cfg, **{"spark.sql.optimizer.dynamicPartitionPruning.enabled": False,
+                       "spark.sql.optimizer.runtime.bloomFilter.enabled": False,
+                       "spark.sql.adaptive.localShuffleReader.enabled": False})
+    assert model.query_latency(off, q)[0] == model.query_latency(cfg, q)[0]
+
+
+def test_mftune_runs_in_spark35_space():
+    """A session over the 128-knob space runs through ``MFTune.run`` and
+    attributes every compression pass through the chain walk."""
+    from repro import obs
+    from repro.core import KnowledgeBase, MFTune, MFTuneOptions
+    from repro.sparksim import TaskSpec, generate_history, spark35_space
+    from repro.tuneapi import Budget
+
+    space = spark35_space()
+    kb = KnowledgeBase()
+    for spec in (TaskSpec("tpcds", 100, "A"), TaskSpec("tpcds", 600, "B")):
+        src = SparkWorkload(spec.benchmark, spec.data_gb, spec.hardware, space=space)
+        kb.add_task(generate_history(src, n_obs=12, n_init=6, seed=1), persist=False)
+    wl = SparkWorkload("tpcds", 600, "A", space=space)
+    tr = obs.Tracer("wide")
+    with obs.tracing(tr):
+        res = MFTune(wl, kb, MFTuneOptions(seed=0)).run(Budget(6 * 3600.0))
+    assert res.n_evaluations > 0 and np.isfinite(res.best_performance)
+    assert set(res.best_config) == set(space.names)
+    counts = tr.metrics.counters_view("shapley/")
+    assert counts.get("chain_kernel", 0) > 0 and "composite_fallback" not in counts
