@@ -27,6 +27,7 @@ from .space import (
     space_backend,
 )
 from .surrogate import (
+    ForestMemo,
     ForestPlane,
     GaussianProcess,
     PackedForest,
@@ -95,6 +96,7 @@ __all__ = [
     "space_backend", "set_log_sampling", "log_sampling",
     "GaussianProcess", "ProbabilisticRandomForest",
     "PackedForest", "ForestPlane", "make_forest", "set_forest_backend", "forest_backend",
+    "ForestMemo",
     "expected_improvement", "rank_aggregate", "aggregate_ranks", "normal_cdf", "score_sources",
     "EI_VAR_FLOOR", "expected_improvement_jax", "aggregate_ranks_jax",
     "set_acquisition_backend", "get_acquisition_backend", "acquisition_backend",

@@ -28,6 +28,7 @@ from .knowledge import TaskRecord
 from .shapley import shapley_values_batch
 from .similarity import TaskWeights, surrogate_for_task
 from .space import BoolKnob, CatKnob, ConfigSpace, FloatKnob, IntKnob, Intervals
+from .surrogate import ForestMemo
 
 __all__ = ["PromisingRegion", "extract_promising_regions", "compress_space", "SpaceCompressor"]
 
@@ -65,12 +66,14 @@ def extract_promising_regions(
     n_permutations: int = 16,
     max_configs: int = 32,
     backend: str = "batched",
+    memo: Optional[ForestMemo] = None,
 ) -> Optional[PromisingRegion]:
     """§5.1 for one source task (or the target acting as its own source).
 
     All promising configs are explained in one batched masked-evaluation
     pass (``shapley_values_batch``); ``backend="loop"`` pins the legacy
-    per-chain path, bit-identical under the shared permutation draws.
+    per-chain path, bit-identical under the shared permutation draws. The
+    task's forest is fitted through ``memo`` when one is given.
     """
     obs = task.full_fidelity()
     if len(obs) < 4:
@@ -85,7 +88,7 @@ def extract_promising_regions(
     # cap SHAP cost: explain the best configs first
     good = sorted(good, key=lambda o: o.performance)[:max_configs]
 
-    model = surrogate_for_task(space, task, seed=seed)
+    model = surrogate_for_task(space, task, seed=seed, memo=memo)
     if model is None:
         return None
     X_all = space.encode_many([o.config for o in obs])
@@ -221,7 +224,8 @@ class SpaceCompressor:
     only re-scaled when weights change. The target task's own region is
     recomputed as its observation set grows. KDE fits / alpha-mass regions
     are additionally memoized across ``compress`` calls (see
-    ``compress_space``'s ``range_cache``).
+    ``compress_space``'s ``range_cache``). Forests are fitted through
+    ``memo`` when one is given.
     """
 
     def __init__(
@@ -230,11 +234,13 @@ class SpaceCompressor:
         alpha: float = 0.65,
         seed: int = 0,
         backend: str = "batched",
+        memo: Optional[ForestMemo] = None,
     ):
         self.space = space
         self.alpha = alpha
         self.seed = seed
         self.backend = backend
+        self.memo = memo
         self._cache: Dict[str, PromisingRegion] = {}
         self._range_cache: "OrderedDict" = OrderedDict()
 
@@ -251,7 +257,8 @@ class SpaceCompressor:
             # silently served by the next non-refresh call
             self._cache.pop(task.task_id, None)
             r = extract_promising_regions(
-                self.space, task, 1.0, seed=self.seed, backend=self.backend
+                self.space, task, 1.0, seed=self.seed, backend=self.backend,
+                memo=self.memo,
             )
             if r is None:
                 return None

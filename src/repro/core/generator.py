@@ -33,7 +33,7 @@ from .acquisition import (
 from .knowledge import TaskRecord
 from .similarity import TaskWeights, surrogate_for_task
 from .space import ConfigBatch, ConfigSpace
-from .surrogate import Surrogate, make_forest
+from .surrogate import ForestMemo, Surrogate, fit_forest
 
 Config = Dict[str, Any]
 
@@ -202,12 +202,14 @@ class CandidateGenerator:
         pool_size: int = 256,
         backend: Optional[str] = None,
         cache_entries: int = 64,
+        memo: Optional[ForestMemo] = None,
     ):
         self.space = space                # full space: defines the surrogate encoding
         self.sample_space = space         # possibly compressed: defines the sampling region
         self.seed = seed
         self.pool_size = pool_size
         self.backend = backend            # packed-forest backend for fitted surrogates
+        self.memo = memo                  # session's ForestMemo (None = fit afresh)
         self._rng = np.random.default_rng(seed)
         self._store = SurrogateStore(max_entries=cache_entries)
         # encoded-exclusion cache: recommend is called once per bracket with
@@ -247,7 +249,8 @@ class CandidateGenerator:
                 with _obs.span("surrogate_fit", source=f"task:{tid}",
                                n_obs=len(task.observations)):
                     m = surrogate_for_task(
-                        self.space, task, seed=self.seed, backend=self.backend
+                        self.space, task, seed=self.seed, backend=self.backend,
+                        memo=self.memo,
                     )
                     if m is None:
                         return None
@@ -283,7 +286,8 @@ class CandidateGenerator:
                     y = np.array(
                         [penalty if o.failed else o.performance for o in all_obs]
                     )
-                    m = make_forest(seed=self.seed, backend=self.backend).fit(X, y)
+                    m = fit_forest(X, y, seed=self.seed, backend=self.backend,
+                                   memo=self.memo)
                     return m, float(min(o.performance for o in ok_obs))
 
             got = self._store.get(f"fid:{d:.6f}:{target.task_id}", len(all_obs), build_fid)

@@ -43,6 +43,7 @@ from .hyperband import HyperbandRunner, Rung, RungTable
 from .knowledge import KnowledgeBase, Observation, TaskRecord
 from .similarity import SimilarityEngine, TaskWeights
 from .space import ConfigSpace, space_backend as _space_backend_ctx
+from .surrogate import ForestMemo
 
 Config = Dict[str, Any]
 
@@ -142,13 +143,18 @@ class MFTune:
             )
             self.kb.add_task(self.target, persist=False)
 
-        self.sim = SimilarityEngine(self.space, self.kb, seed=self.opt.seed)
+        # one memo of fitted forests per tuner: similarity, compression and
+        # the generator fit the same source forests (and the CV the same
+        # folds), so each distinct fit runs once in this instance's sessions
+        self.memo = ForestMemo()
+        self.sim = SimilarityEngine(self.space, self.kb, seed=self.opt.seed, memo=self.memo)
         self.compressor = SpaceCompressor(
             self.space, alpha=self.opt.alpha, seed=self.opt.seed,
-            backend=self.opt.shapley_backend,
+            backend=self.opt.shapley_backend, memo=self.memo,
         )
         self.gen = CandidateGenerator(
-            self.space, seed=self.opt.seed, backend=self.opt.surrogate_backend
+            self.space, seed=self.opt.seed, backend=self.opt.surrogate_backend,
+            memo=self.memo,
         )
         self.ws_queue = WarmStartQueue()
         self.hb = HyperbandRunner(
@@ -431,6 +437,7 @@ class MFTune:
         # the legacy TuningResult fields as views over it
         m = self.metrics
         m.absorb_counters("surrogate_store/", self.gen.cache_stats)
+        m.absorb_counters("forest_memo/", self.memo.stats)
         plane_now = plane_cache_stats()
         m.absorb_counters("plane_cache/", {
             **{k: plane_now[k] - plane0[k] for k in ("hits", "misses", "evictions")},

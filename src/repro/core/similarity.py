@@ -25,7 +25,7 @@ from .. import obs as _obs
 from .gbm import GradientBoostedTrees
 from .knowledge import KnowledgeBase, TaskRecord
 from .space import ConfigSpace
-from .surrogate import Surrogate, make_forest
+from .surrogate import ForestMemo, Surrogate, fit_forest
 
 __all__ = [
     "kendall_tau",
@@ -55,8 +55,10 @@ def surrogate_for_task(
     fidelity: Optional[float] = None,
     seed: int = 0,
     backend: Optional[str] = None,
+    memo: Optional[ForestMemo] = None,
 ) -> Optional[Surrogate]:
-    """Fit a PRF on a task's observations in the given space encoding."""
+    """Fit a PRF on a task's observations in the given space encoding
+    (through ``memo`` when one is given)."""
     if fidelity is None:
         obs = task.successful()
     else:
@@ -65,7 +67,7 @@ def surrogate_for_task(
         return None
     X = space.encode_many([o.config for o in obs])
     y = np.array([o.performance for o in obs])
-    return make_forest(seed=seed, backend=backend).fit(X, y)
+    return fit_forest(X, y, seed=seed, backend=backend, memo=memo)
 
 
 def eq2_similarity(
@@ -110,12 +112,15 @@ class MetaSimilarityModel:
         # symmetric encoding: |diff| and elementwise product stabilize learning
         return np.concatenate([np.abs(fi - fj), fi * fj])
 
-    def fit(self, space: ConfigSpace, kb: KnowledgeBase, task_ids: Sequence[str]) -> "MetaSimilarityModel":
+    def fit(
+        self, space: ConfigSpace, kb: KnowledgeBase, task_ids: Sequence[str],
+        memo: Optional[ForestMemo] = None,
+    ) -> "MetaSimilarityModel":
         rng = np.random.default_rng(self.seed)
         tasks = [kb.get(t) for t in task_ids if kb.get(t).meta_features is not None]
         models: Dict[str, Surrogate] = {}
         for t in tasks:
-            m = surrogate_for_task(space, t, seed=self.seed)
+            m = surrogate_for_task(space, t, seed=self.seed, memo=memo)
             if m is not None:
                 models[t.task_id] = m
         tasks = [t for t in tasks if t.task_id in models]
@@ -163,7 +168,11 @@ class TaskWeights:
 
 
 class SimilarityEngine:
-    """Implements §4.2 end-to-end: prediction warm start -> Eq. 2 -> weights."""
+    """Implements §4.2 end-to-end: prediction warm start -> Eq. 2 -> weights.
+
+    ``memo`` (a session's :class:`ForestMemo`) serves the source forests,
+    the meta-model's and the target's CV folds; None fits each afresh.
+    """
 
     def __init__(
         self,
@@ -172,12 +181,14 @@ class SimilarityEngine:
         seed: int = 0,
         p_threshold: float = 0.05,
         cv_folds: int = 4,
+        memo: Optional[ForestMemo] = None,
     ):
         self.space = space
         self.kb = kb
         self.seed = seed
         self.p_threshold = p_threshold
         self.cv_folds = cv_folds
+        self.memo = memo
         self.meta_model: Optional[MetaSimilarityModel] = None
         self._source_models: Dict[str, Surrogate] = {}
 
@@ -186,11 +197,15 @@ class SimilarityEngine:
         if self.meta_model is not None:
             return
         ids = [t.task_id for t in self.kb.source_tasks(target.task_id)]
-        self.meta_model = MetaSimilarityModel(seed=self.seed).fit(self.space, self.kb, ids)
+        self.meta_model = MetaSimilarityModel(seed=self.seed).fit(
+            self.space, self.kb, ids, memo=self.memo
+        )
 
     def source_model(self, task_id: str) -> Optional[Surrogate]:
         if task_id not in self._source_models:
-            m = surrogate_for_task(self.space, self.kb.get(task_id), seed=self.seed)
+            m = surrogate_for_task(
+                self.space, self.kb.get(task_id), seed=self.seed, memo=self.memo
+            )
             if m is None:
                 return None
             self._source_models[task_id] = m
@@ -215,7 +230,7 @@ class SimilarityEngine:
             tr, te = folds != f, folds == f
             if tr.sum() < 2 or te.sum() < 1:
                 return 0.0
-            m = make_forest(seed=self.seed).fit(X[tr], y[tr])
+            m = fit_forest(X[tr], y[tr], seed=self.seed, memo=self.memo)
             preds[te] = m.predict_mean(X[te])
         tau, _ = kendall_tau(preds, y)
         return max(tau, 0.0)

@@ -36,6 +36,7 @@ The default path is pure numpy; data sets here are O(10^2-10^3) points.
 from __future__ import annotations
 
 import contextlib
+from collections import OrderedDict
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -51,6 +52,8 @@ __all__ = [
     "GaussianProcess",
     "Surrogate",
     "make_forest",
+    "fit_forest",
+    "ForestMemo",
     "set_forest_backend",
     "get_forest_backend",
     "forest_backend",
@@ -804,6 +807,75 @@ def forest_backend(backend: str):
 def make_forest(seed: int = 0, backend: Optional[str] = None, **kwargs) -> ProbabilisticRandomForest:
     """Packed factory: every surrogate stack in the repo builds PRFs here."""
     return ProbabilisticRandomForest(seed=seed, backend=backend, **kwargs)
+
+
+class ForestMemo:
+    """The fitted forests of one tuning session, keyed by what fixes them.
+
+    Several owners fit the same forest: a source task's PRF gets the same
+    encoded observations, seed and hyperparameters from similarity (Eq. 2
+    and the meta-model), compression and the generator, and the target's
+    k-fold CV refits the same folds while its full-fidelity data is
+    unchanged. ``fit`` returns the stored forest when the key matches, so
+    each distinct fit runs once. The key is the resolved backend, the seed,
+    every hyperparameter, and the shapes and exact bytes of the float64 X
+    and y; the dict compares those bytes, so no digest collision can serve
+    a wrong forest. Owners treat the forests they get as read-only.
+
+    Only a real fit opens a ``forest_fit`` span; ``stats`` counts hits,
+    misses and LRU evictions. One memo serves one tuner instance: a memo
+    that outlived it would carry one session's fits into the next.
+    """
+
+    # ample for a session's source forests plus its recent target fits
+    # (a TPC-H session at the benchmark's size makes 80 distinct fits)
+    max_entries = 256
+
+    def __init__(self):
+        self._entries: "OrderedDict[tuple, ProbabilisticRandomForest]" = OrderedDict()
+        self.hits = 0
+        self.misses = 0
+        self.evictions = 0
+
+    @property
+    def stats(self) -> dict:
+        return {"hits": self.hits, "misses": self.misses,
+                "evictions": self.evictions, "size": len(self._entries)}
+
+    def fit(
+        self, X: np.ndarray, y: np.ndarray, seed: int = 0,
+        backend: Optional[str] = None, **hyper,
+    ) -> ProbabilisticRandomForest:
+        X = np.atleast_2d(np.asarray(X, dtype=float))
+        y = np.asarray(y, dtype=float)
+        forest = make_forest(seed=seed, backend=backend, **hyper)
+        key = (
+            forest.backend, forest.seed, forest.n_trees, forest.max_depth,
+            forest.min_samples_split, forest.min_samples_leaf, forest.bootstrap,
+            X.shape, X.tobytes(), y.shape, y.tobytes(),
+        )
+        got = self._entries.get(key)
+        if got is not None:
+            self._entries.move_to_end(key)
+            self.hits += 1
+            return got
+        self.misses += 1
+        self._entries[key] = forest.fit(X, y)
+        while len(self._entries) > self.max_entries:
+            self._entries.popitem(last=False)
+            self.evictions += 1
+        return forest
+
+
+def fit_forest(
+    X: np.ndarray, y: np.ndarray, seed: int = 0, backend: Optional[str] = None,
+    memo: Optional[ForestMemo] = None,
+) -> ProbabilisticRandomForest:
+    """Fit a PRF at the default hyperparameters, through ``memo`` when one
+    is given."""
+    if memo is not None:
+        return memo.fit(X, y, seed=seed, backend=backend)
+    return make_forest(seed=seed, backend=backend).fit(X, y)
 
 
 # ---------------------------------------------------------------------------
